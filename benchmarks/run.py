@@ -61,7 +61,7 @@ MODELS = ["optane-clwb", "eadr", "cxl"]
 
 
 def _emit_manifest(subcommand: str, args, rows, headline,
-                   phases=None, wall_s=None, extra=None):
+                   phases=None, wall_s=None, extra=None, device=None):
     """Write the versioned run manifest for a subcommand.
 
     The path follows the ``--out`` CSV convention (``x.csv`` ->
@@ -74,7 +74,7 @@ def _emit_manifest(subcommand: str, args, rows, headline,
         return None
     man = build_manifest(subcommand=subcommand, config=vars(args),
                          metrics=rows, headline=headline, phases=phases,
-                         wall_s=wall_s, extra=extra)
+                         wall_s=wall_s, extra=extra, device=device)
     path = write_manifest(man, path)
     print(f"# wrote manifest {path}")
     return path
@@ -608,7 +608,8 @@ def fastpath_smoke_main(argv) -> None:
 # `run.py fleet` CSV schema -- tests/test_docs_refs.py checks that the
 # column list quoted in docs/fleet.md matches this constant.
 FLEET_CSV_COLUMNS = [
-    "queue", "model", "contention", "backend", "devices", "instances",
+    "queue", "model", "contention", "backend", "platform", "device_kind",
+    "devices", "instances",
     "ops_per_instance", "total_ops", "chunk", "bails", "residents",
     "build_s", "run_s", "fleet_mops_per_s", "sim_ns_per_op",
     "fences_per_op", "post_flush_per_op", "checked", "check_ok",
@@ -622,7 +623,7 @@ def fleet_main(argv) -> None:
     user/tenant, one thread each) as a single vectorized array program
     (repro.fleet): each queue x model compiled schedule is lowered to
     stacked event-count/effect arrays and driven by a vmapped lax.scan
-    stepper sharded across forced XLA host devices; instances hitting a
+    stepper sharded over the first --devices JAX devices; instances hitting a
     fast-path bail condition fall out to the real per-instance executor
     and rejoin at the next chunk boundary.  ``--check N`` re-runs N
     sampled instances per cell on independent ``run_batched`` harnesses
@@ -650,14 +651,16 @@ def fleet_main(argv) -> None:
     ap.add_argument("--backend",
                     choices=["auto", "numpy", "jax", "jax-opcode", "pallas"],
                     default="numpy",
-                    help="numpy (default; fastest on host CPU), jax (the "
+                    help="numpy (default; the host reference), jax (the "
                          "sharded unrolled XLA path), jax-opcode (the "
                          "opcode-interpreting scan: depth-independent "
-                         "compile), pallas (the opcode interpreter as a "
-                         "Pallas chunk kernel; interpret mode off-TPU), or "
-                         "auto (jax if importable)")
-    ap.add_argument("--devices", type=int, default=8,
-                    help="forced XLA host devices for the jax mesh")
+                         "compile; the TPU path), pallas (the opcode "
+                         "interpreter as a Pallas chunk kernel; CPU "
+                         "interpret mode only, Mosaic refuses it on TPU), "
+                         "or auto (jax-opcode on TPU, numpy otherwise)")
+    ap.add_argument("--devices", type=int, default=1,
+                    help="jax mesh size: shard instances over the first N "
+                         "JAX devices (fails if fewer exist)")
     ap.add_argument("--chunk", type=int, default=48,
                     help="plan steps per vector chunk (bail/rejoin "
                          "granularity)")
@@ -681,10 +684,11 @@ def fleet_main(argv) -> None:
                          "--out as <stem>.manifest.json)")
     args = ap.parse_args(argv)
     from repro.fleet import (FleetConfig, check_instances,
-                             ensure_host_devices, run_fleet)
+                             enable_compile_cache, run_fleet)
     if args.backend != "numpy":
-        ensure_host_devices(args.devices)
+        enable_compile_cache()
     rows, failures = [], []
+    device = None
     headline = {}
     t_run0 = time.perf_counter()
     print(f"# fleet: {args.instances} instances x {args.ops} ops "
@@ -718,9 +722,13 @@ def fleet_main(argv) -> None:
                                 f"{qname}/{model}/{cont}: instance "
                                 f"{r['instance']} fleet Stats != run_batched "
                                 f"Stats")
+                device = res.device
+                platform = device["platform"] if device else "host"
                 rows.append({
                     "queue": qname, "model": model, "contention": cont,
-                    "backend": res.backend, "devices": res.devices,
+                    "backend": res.backend, "platform": platform,
+                    "device_kind": device["kind"] if device else "",
+                    "devices": res.devices,
                     "instances": args.instances,
                     "ops_per_instance": args.ops, "total_ops": total,
                     "chunk": args.chunk, "bails": res.bails,
@@ -739,13 +747,17 @@ def fleet_main(argv) -> None:
                       f"mops={res.ops_per_sec / 1e6:.2f};"
                       f"sim_ns_per_op={sim_ns:.1f};"
                       f"fences_per_op={agg.fences / total:.2f};"
-                      f"backend={res.backend};bails={res.bails};"
+                      f"backend={res.backend};platform={platform};"
+                      f"bails={res.bails};"
                       f"checked={check_ok}/{checked}")
                 # the numpy reference keeps the legacy trajectory cell
-                # name; other backends get backend-qualified cells so the
-                # perf gate never compares across backends
+                # name; other backends get backend-qualified cells, and
+                # off-CPU platforms platform-qualified ones, so the perf
+                # gate never compares across backends or host and device
                 cell = ("wall_us_per_op" if res.backend == "numpy"
                         else f"{res.backend}_wall_us_per_op")
+                if platform not in ("host", "cpu"):
+                    cell = f"{platform}_{cell}"
                 headline[f"fleet/{model}/{cont}/{qname}/{cell}"] = \
                     round(res.run_s * 1e6 / total, 4)
     if args.out:
@@ -755,7 +767,7 @@ def fleet_main(argv) -> None:
             w.writerows(rows)
         print(f"# wrote {len(rows)} rows to {args.out}")
     _emit_manifest("fleet", args, rows, headline,
-                   wall_s=time.perf_counter() - t_run0)
+                   wall_s=time.perf_counter() - t_run0, device=device)
     if failures:
         for msg in failures:
             print(f"# FLEET CHECK FAILURE: {msg}", file=sys.stderr)

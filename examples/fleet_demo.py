@@ -7,7 +7,8 @@ independent per-instance ``run_batched`` runs (docs/fleet.md).
 """
 import argparse
 
-from repro.fleet import FleetConfig, check_instances, run_fleet
+from repro.fleet import (FleetConfig, check_instances, enable_compile_cache,
+                         run_fleet)
 
 
 def main() -> None:
@@ -17,13 +18,16 @@ def main() -> None:
     ap.add_argument("--ops", type=int, default=96,
                     help="plan steps per instance (default 96)")
     ap.add_argument("--backend", default="auto",
-                    choices=("auto", "numpy", "jax"))
+                    choices=("auto", "numpy", "jax", "jax-opcode"),
+                    help="auto: jax-opcode on a TPU, numpy otherwise")
     ap.add_argument("--quick", action="store_true",
                     help="reduced fleet for CI smoke (2000 x 48, numpy)")
     args = ap.parse_args()
     instances, ops, backend = args.instances, args.ops, args.backend
     if args.quick:
         instances, ops, backend = 2_000, 48, "numpy"
+    if backend != "numpy":
+        enable_compile_cache()
 
     for queue in ("DurableMSQ", "OptUnlinkedQ", "OptLinkedQ"):
         cfg = FleetConfig(queue=queue, model="optane-clwb",
@@ -33,8 +37,10 @@ def main() -> None:
         checks = check_instances(res, sample=4)
         ok = sum(1 for c in checks if c["ok"])
         assert ok == len(checks), f"{queue}: fleet diverged from run_batched"
+        where = (f"{res.backend} ({res.device['platform']})" if res.device
+                 else f"{res.backend} (host)")
         print(f"{queue:14s} {instances} instances x {ops} ops on "
-              f"{res.backend}: {res.ops_per_sec / 1e6:.2f} Mops/s wall, "
+              f"{where}: {res.ops_per_sec / 1e6:.2f} Mops/s wall, "
               f"{agg.time_ns / res.total_ops:.1f} sim-ns/op, "
               f"{agg.fences / res.total_ops:.2f} fences/op, "
               f"bails={res.bails}, checked {ok}/{len(checks)} bit-identical")

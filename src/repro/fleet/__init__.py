@@ -15,11 +15,10 @@ one thread each) as a single batched array program:
   integer state, replicate it across N instances (construction is
   deterministic, so every instance shares the template's address layout);
 * :mod:`repro.fleet.stepper` -- the numpy reference stepper (mask-vectorized
-  over instances; also the fallback when jax is unavailable);
+  over instances; ``auto`` picks it off-TPU);
 * :mod:`repro.fleet.jaxexec` -- the jax backends: a per-instance step
   function, ``jax.vmap`` over the fleet, ``lax.scan`` over the op stream,
-  sharded across forced host devices
-  (``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  Three
+  sharded over the first ``FleetConfig.devices`` JAX devices.  Three
   flavors: ``jax`` (unrolled trace), ``jax-opcode`` (interprets the
   fixed-width opcode tables emitted by the lowering, so compile time is
   independent of schedule depth) and ``pallas`` (the same opcode
@@ -36,11 +35,12 @@ per-instance fleet Stats (every counter *and* ``time_ns``) are
 **bit-identical** to N independent :meth:`repro.core.harness.QueueHarness.
 run_batched` runs (``tests/test_fleet_equivalence.py``).  See docs/fleet.md.
 """
+from .jaxexec import enable_compile_cache
 from .runner import (FleetConfig, FleetResult, build_fleet, check_instances,
-                     ensure_host_devices, fleet_kinds, run_fleet)
+                     fleet_kinds, run_fleet)
 from .state import build_template
 
 __all__ = [
     "FleetConfig", "FleetResult", "build_fleet", "build_template",
-    "check_instances", "ensure_host_devices", "fleet_kinds", "run_fleet",
+    "check_instances", "enable_compile_cache", "fleet_kinds", "run_fleet",
 ]

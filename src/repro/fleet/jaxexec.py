@@ -9,12 +9,13 @@ step as masked straight-line code (no ``lax.cond`` -- the fleet's whole
 premise is that each op is a handful of gathers/scatters, so executing the
 non-selected program under a False mask is cheaper than divergence).
 
-Sharding uses the CPU-mesh trick: ``XLA_FLAGS=
---xla_force_host_platform_device_count=8`` (set by
-:func:`repro.fleet.runner.ensure_host_devices` before jax's first import)
-splits the host into 8 XLA devices; a 1D mesh over the instance axis then
-gives device parallelism without any accelerator.  The instance axis is
-padded to a device multiple; padding rows are born inactive.
+The instance axis is sharded over a 1D mesh of the first ``devices`` JAX
+devices (TPU chips; on the CPU platform, host devices, of which there
+are more only where the caller's environment sets ``XLA_FLAGS=
+--xla_force_host_platform_device_count=N``).  It is padded to a device
+multiple; padding rows are born inactive.  Each chunk length's step is
+compiled ahead of time (:meth:`JaxBackend.prepare`), so compilation is
+set-up and the chunk loop runs only compiled code.
 
 All arrays are int32/uint8 -- volatile addresses are offsets, counts are
 int32 deltas (converted back to int64 on the host) -- so the backend never
@@ -36,6 +37,9 @@ binding + allocations):
   body of the Pallas kernel in :mod:`repro.kernels.fleet_step`.
 """
 from __future__ import annotations
+
+import os
+from pathlib import Path
 
 import numpy as np
 
@@ -64,6 +68,36 @@ _ARRAY_FIELDS = ("cached", "finval", "everfl", "persisted", "vtouched",
 _SCALAR_FIELDS = ("head", "length", "dummy_p", "dummy_v", "nfree", "cursor",
                   "nvfree", "vcursor", "nlimbo", "epoch", "opsctr",
                   "active", "bail_at")
+
+
+# the checkout's root: src/repro/fleet/jaxexec.py -> parents[3]
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  A ``JAX_COMPILATION_CACHE_DIR`` set in the environment is
+    honoured as JAX read it, and nothing is set in code.  Otherwise the
+    cache goes to the checkout's ``.jax_cache``: a fixed path, because the
+    path is part of the cache's key.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
+
+
+def state_arrays(state: FleetState) -> dict:
+    """The chunk step's state dict for one FleetState batch, as host
+    arrays: the on-device layout (``slot_<attr>`` keys, int32 counts)
+    before padding."""
+    st = {name: getattr(state, name)
+          for name in _ARRAY_FIELDS + _SCALAR_FIELDS}
+    st["counts"] = state.counts.astype(np.int32)
+    for attr, arr in state.slots.items():
+        st["slot_" + attr] = arr
+    return st
 
 
 def _advance_one(jnp, dims, c):
@@ -456,51 +490,40 @@ def make_opcode_chunk_fn(jax, programs, dims):
 
 
 class JaxBackend:
-    """Device-resident fleet state; same protocol as NumpyBackend."""
+    """Device-resident fleet state; same protocol as NumpyBackend.  The
+    instance axis is sharded over the first ``devices`` JAX devices."""
     name = "jax"
 
     def __init__(self, template: Template, state: FleetState,
-                 devices: int = 8):
+                 devices: int = 1):
         import jax
-        import jax.numpy as jnp
-        self.jax, self.jnp = jax, jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        self.jax = jax
         self.t = template
         self.n = state.n
-        self._setup_layout(state)
+        self.devices = devices
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("i",))
+        self.sharding = NamedSharding(mesh, PartitionSpec("i"))
+        self.replicated = NamedSharding(mesh, PartitionSpec())
+        self.npad = self._padded(state.n)
 
-        def put(a, pad_value=None):
+        def put(name, a):
             pad = self.npad - self.n
             if pad:
-                tile = (np.repeat(a[:1], pad, axis=0) if pad_value is None
-                        else np.full((pad,) + a.shape[1:], pad_value,
-                                     dtype=a.dtype))
+                tile = (np.zeros((pad,), dtype=a.dtype) if name == "active"
+                        else np.repeat(a[:1], pad, axis=0))
                 a = np.concatenate([a, tile], axis=0)
             return self._put(a)
 
-        st = {}
-        for name in _ARRAY_FIELDS:
-            st[name] = put(getattr(state, name))
-        for name in _SCALAR_FIELDS:
-            pad_value = False if name == "active" else None
-            st[name] = put(getattr(state, name), pad_value)
-        st["counts"] = put(state.counts.astype(np.int32))
-        for attr, arr in state.slots.items():
-            st["slot_" + attr] = put(arr)
-        self.st = st
+        self.st = {name: put(name, a)
+                   for name, a in state_arrays(state).items()}
         self._fn = self._make_fn()
+        self._exe = {}
 
-    def _setup_layout(self, state: FleetState) -> None:
-        """Instance-axis padding + sharding.  The base backend shards a 1D
-        mesh over every host device; subclasses override."""
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec
-        ndev = len(self.jax.devices())
-        self.npad = -(-state.n // ndev) * ndev
-        mesh = Mesh(np.array(self.jax.devices()), ("i",))
-        self.sharding = NamedSharding(mesh, PartitionSpec("i"))
+    def _padded(self, n: int) -> int:
+        return -(-n // self.devices) * self.devices
 
     def _put(self, a):
-        if self.sharding is None:
-            return self.jax.device_put(a)
         return self.jax.device_put(a, self.sharding)
 
     def _make_fn(self):
@@ -508,13 +531,32 @@ class JaxBackend:
                                           self.t.dims),
                             donate_argnums=(0,))
 
+    def _compiled(self, C: int):
+        """The chunk step for chunk length ``C``, compiled ahead of time
+        for the state's shapes and shardings."""
+        if C not in self._exe:
+            jax = self.jax
+            st = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                          sharding=v.sharding)
+                  for k, v in self.st.items()}
+            kc = jax.ShapeDtypeStruct((self.npad, C), np.uint8,
+                                      sharding=self.sharding)
+            oi = jax.ShapeDtypeStruct((C,), np.int32,
+                                      sharding=self.replicated)
+            self._exe[C] = self._fn.lower(st, kc, oi).compile()
+        return self._exe[C]
+
+    def prepare(self, chunk_lengths) -> None:
+        for C in chunk_lengths:
+            self._compiled(C)
+
     def run_chunk(self, kinds: np.ndarray, start: int) -> None:
         C = kinds.shape[0]
         kc = np.zeros((self.npad, C), dtype=np.uint8)
         kc[:self.n] = kinds.T
-        kc = self._put(kc)
-        oi = self.jnp.arange(start, start + C, dtype=self.jnp.int32)
-        self.st = self._fn(self.st, kc, oi)
+        oi = np.arange(start, start + C, dtype=np.int32)
+        self.st = self._compiled(C)(
+            self.st, self._put(kc), self.jax.device_put(oi, self.replicated))
 
     def poll(self):
         bail_at = np.asarray(self.st["bail_at"])[:self.n]
@@ -522,29 +564,33 @@ class JaxBackend:
         fresh = (~active) & (bail_at >= 0)
         return np.nonzero(fresh)[0], bail_at
 
-    def rejoin(self, i: int, row: dict) -> None:
+    def _set_rows(self, i: int, values: dict) -> None:
         st = dict(self.st)
+        for name, val in values.items():
+            # re-place: the compiled step accepts only its own shardings
+            st[name] = self._put(st[name].at[i].set(val))
+        self.st = st
+
+    def rejoin(self, i: int, row: dict) -> None:
+        values = {}
         for name, val in row.items():
             if name == "slots":
                 for attr, v in val.items():
-                    st["slot_" + attr] = st["slot_" + attr].at[i].set(v)
+                    values["slot_" + attr] = v
             elif name == "counts":
-                st["counts"] = st["counts"].at[i].set(
-                    val.astype(np.int32))
+                values["counts"] = val.astype(np.int32)
             else:
-                st[name] = st[name].at[i].set(val)
-        st["active"] = st["active"].at[i].set(True)
-        st["bail_at"] = st["bail_at"].at[i].set(-1)
-        self.st = st
+                values[name] = val
+        values["active"] = True
+        values["bail_at"] = -1
+        self._set_rows(i, values)
 
     def retire_resident(self, i: int) -> None:
         from .runner import RESIDENT
-        st = dict(self.st)
-        st["active"] = st["active"].at[i].set(False)
-        st["bail_at"] = st["bail_at"].at[i].set(RESIDENT)
-        self.st = st
+        self._set_rows(i, {"active": False, "bail_at": RESIDENT})
 
     def counts(self) -> np.ndarray:
+        self.jax.block_until_ready(self.st)
         return np.asarray(self.st["counts"])[:self.n].astype(np.int64)
 
 
@@ -564,28 +610,27 @@ class OpcodeJaxBackend(JaxBackend):
 class PallasBackend(JaxBackend):
     """Opcode interpreter as a Pallas kernel: instances map to the grid in
     blocks, each program id steps its block's state rows through the whole
-    chunk.  On hosts without an accelerator the kernel runs in Pallas
-    interpret mode (still the kernel's dataflow, evaluated by XLA:CPU) --
-    that is what CI's ``fleet-pallas-smoke`` exercises; on TPU the same
-    kernel compiles to Mosaic.  Single-device: the grid replaces the mesh
-    sharding of the base backend."""
+    chunk.  It runs in Pallas interpret mode on the CPU platform (still
+    the kernel's dataflow, evaluated by XLA:CPU) -- what CI's
+    ``fleet-pallas-smoke`` exercises.  Mosaic refuses the kernel on TPU,
+    so the runner rejects ``pallas`` off the CPU before building anything.
+    Single-device: the grid replaces the mesh sharding of the base
+    backend."""
     name = "pallas"
-    PHASE_COMPILED = "kernel-launch"
-    PHASE_INTERPRET = "kernel-interpret"
+    chunk_phase = "kernel-interpret"
     block = 128
 
-    def _setup_layout(self, state: FleetState) -> None:
-        self.sharding = None
-        self.interpret = self.jax.default_backend() != "tpu"
-        self.chunk_phase = (self.PHASE_INTERPRET if self.interpret
-                            else self.PHASE_COMPILED)
+    def __init__(self, template: Template, state: FleetState,
+                 devices: int = 1):
         # shrink the block for tiny fleets (tests): padding 5 instances to
         # a 128-row block would cost 25x the interpret-mode work
         self.block = min(self.block, -(-state.n // 8) * 8)
-        self.npad = -(-state.n // self.block) * self.block
+        super().__init__(template, state, devices)
+
+    def _padded(self, n: int) -> int:
+        return -(-n // self.block) * self.block
 
     def _make_fn(self):
         from ..kernels.fleet_step import make_pallas_chunk_fn
         return make_pallas_chunk_fn(self.jax, self.t.programs, self.t.dims,
-                                    block=self.block,
-                                    interpret=self.interpret)
+                                    block=self.block, interpret=True)

@@ -27,8 +27,7 @@ which inject unclamped plans via ``run_fleet(cfg, kinds=...)``.
 """
 from __future__ import annotations
 
-import os
-import sys
+import importlib.util
 import time
 from dataclasses import dataclass, field, replace
 from typing import List, Optional
@@ -56,7 +55,7 @@ class FleetConfig:
     p_deq: float = 0.5
     chunk: int = 64                 # plan steps per vector chunk
     backend: str = "auto"           # auto | numpy | jax | jax-opcode | pallas
-    devices: int = 8                # forced host devices for the jax mesh
+    devices: int = 1                # jax mesh size: the first N devices
     batch: int = 0                  # instances per state batch (0 = all)
     contention: str = "off"         # CSV label; one thread per instance, so
                                     # contended counts == uncontended ones
@@ -78,9 +77,10 @@ class FleetResult:
     kinds: np.ndarray
     bails: int                      # bail events (replay+rejoin round trips)
     residents: int                  # instances that finished on Python path
-    build_s: float
+    build_s: float                  # set-up, compilation included
     run_s: float
     template: Template = field(repr=False, default=None)
+    device: Optional[dict] = None   # {platform, kind, count}; None = numpy
 
     @property
     def total_ops(self) -> int:
@@ -97,19 +97,6 @@ class FleetResult:
         """Fleet-aggregate Stats: the elementwise sum of every instance's
         counters (time_ns = total simulated nanoseconds across the fleet)."""
         return self.template.harness.nvram._stats_of(self.counts.sum(axis=0))
-
-
-def ensure_host_devices(n: int = 8) -> bool:
-    """Force n XLA host devices (the SNIPPETS.md CPU-mesh trick).  Only
-    effective before jax's first import: returns False (and changes
-    nothing) if jax is already loaded."""
-    if "jax" in sys.modules:
-        return False
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
-    return True
 
 
 def fleet_kinds(instances: int, ops: int, seed: int = 0,
@@ -156,6 +143,9 @@ class NumpyBackend:
         self.t = template
         self.st = state
 
+    def prepare(self, chunk_lengths) -> None:
+        pass                        # nothing to compile
+
     def run_chunk(self, kinds: np.ndarray, start: int) -> None:
         run_chunk_numpy(self.t.programs, self.t.dims, self.st, kinds, start)
 
@@ -177,26 +167,59 @@ class NumpyBackend:
         return self.st.counts
 
 
+BACKENDS = ("auto", "numpy", "jax", "jax-opcode", "pallas")
+
+PALLAS_REFUSAL = (
+    "the pallas fleet kernel runs only in interpret mode on the CPU "
+    "platform: Mosaic refuses it on TPU (NotImplementedError: "
+    "Unimplemented primitive in Pallas TPU lowering: dynamic_slice, from "
+    "the opcode interpreter's per-instance gathers); use --backend "
+    "jax-opcode on TPU")
+
+
 def _resolve_backend(name: str, devices: int):
-    """-> (backend_name, device_count).  'auto' prefers jax, falls back to
-    numpy if jax is unavailable; the explicit jax-family names
-    ('jax', 'jax-opcode', 'pallas') raise if jax is missing.  Forcing the
-    host-device count only works if jax has not been imported yet
-    (harmless otherwise)."""
-    if name == "numpy":
+    """-> (backend_name, device_count), decided from the JAX platform.
+
+    ``auto`` is ``jax-opcode`` when JAX's devices are TPUs and the numpy
+    reference otherwise (including when jax is not installed).  The jax
+    backends shard over the first ``devices`` devices and raise if fewer
+    exist; ``pallas`` is single-device and runs only on the CPU platform,
+    in interpret mode, because Mosaic refuses the kernel on TPU."""
+    if name not in BACKENDS:
+        raise ValueError(f"unknown fleet backend {name!r}; "
+                         f"choose from {', '.join(BACKENDS)}")
+    if devices < 1:
+        raise ValueError(f"devices must be >= 1, got {devices}")
+    if name == "numpy" or (name == "auto"
+                           and importlib.util.find_spec("jax") is None):
         return "numpy", 1
-    try:
-        ensure_host_devices(devices)
-        import jax
-        if name == "pallas":
-            return "pallas", 1          # grid-parallel, single device
-        if name == "jax-opcode":
-            return "jax-opcode", len(jax.devices())
-        return "jax", len(jax.devices())
-    except Exception:
-        if name != "auto":
-            raise
-        return "numpy", 1
+    import jax
+    found = jax.devices()
+    platform = found[0].platform
+    if name == "auto":
+        if platform != "tpu":
+            return "numpy", 1
+        name = "jax-opcode"
+    if name == "pallas":
+        if platform != "cpu":
+            raise NotImplementedError(f"{PALLAS_REFUSAL} (platform is "
+                                      f"{platform!r})")
+        if devices != 1:
+            raise ValueError("the pallas backend is single-device; "
+                             f"got devices={devices}")
+        return "pallas", 1
+    if devices > len(found):
+        raise ValueError(f"{name} backend asked for a {devices}-device mesh "
+                         f"but JAX has {len(found)} {platform} device(s)")
+    return name, devices
+
+
+def device_info(devices: int) -> dict:
+    """The JAX device a jax backend ran on, as results record it:
+    ``{platform, kind, count}`` of the mesh's first ``devices`` devices."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind, "count": devices}
 
 
 def _make_backend(name: str, template: Template, state, devices: int):
@@ -252,17 +275,13 @@ _NULL = _NullScope()
 
 
 def _run_batch(template: Template, cfg: FleetConfig, kinds: np.ndarray,
-               backend_name: str, devices: int, base: int,
-               prof=_NULL, hb=_NULL):
-    """Run one contiguous instance batch; kinds columns are the batch's
-    plans, ``base`` the batch's first global instance id (labels only).
-    ``prof``/``hb`` are an optional phase profiler and heartbeat (both
-    observation-only; defaults are no-ops)."""
+               backend, prof=_NULL, hb=_NULL):
+    """Run one contiguous instance batch, already uploaded and compiled by
+    ``backend``, through the chunk loop; kinds columns are the batch's
+    plans.  Returns when the device has finished and the counts are back
+    on the host.  ``prof``/``hb`` are an optional phase profiler and
+    heartbeat (both observation-only; defaults are no-ops)."""
     n = kinds.shape[1]
-    prof.push("lowering")
-    state = replicate(template.row, template.dims, n)
-    backend = _make_backend(backend_name, template, state, devices)
-    prof.pop()
     resident_counts = {}
     bails = residents = 0
     chunk_phase = getattr(backend, "chunk_phase", "chunk-step")
@@ -309,16 +328,22 @@ def run_fleet(cfg: FleetConfig, fleet: Optional[Fleet] = None,
     """Build (unless given) and run one fleet cell.  ``kinds`` overrides
     the generated plans (the bail/rejoin tests inject unclamped plans).
 
+    ``build_s`` is set-up: template, plans, state upload and the chunk
+    step's compilation (every chunk length, ahead of time).  ``run_s`` is
+    the chunk loop, bail replays included, up to the device finishing the
+    state and the counts reaching the host.
+
     ``profile`` attaches an observation-only phase profiler (phases:
     ``lowering``, ``chunk-step``, ``poll``, ``bail-replay``,
     ``resident-replay``; the pallas backend replaces ``chunk-step`` with
-    its ``chunk_phase`` -- ``kernel-launch`` or ``kernel-interpret``);
+    its ``chunk_phase``, ``kernel-interpret``);
     ``heartbeat`` a :class:`repro.obs.Heartbeat`
     that emits periodic progress lines.  Neither changes counts."""
     prof = profile if profile is not None else _NULL
     hb = heartbeat if heartbeat is not None else _NULL
     t0 = time.perf_counter()
     prof.push("lowering")
+    backend_name, devices = _resolve_backend(cfg.backend, cfg.devices)
     if fleet is None:
         fleet = build_fleet(cfg)
     if kinds is not None:
@@ -327,32 +352,44 @@ def run_fleet(cfg: FleetConfig, fleet: Optional[Fleet] = None,
             raise ValueError(
                 f"kinds shape {kinds.shape} != {(cfg.ops, cfg.instances)}")
         fleet = replace(fleet, kinds=kinds)
-    backend_name, devices = _resolve_backend(cfg.backend, cfg.devices)
     prof.pop()
     build_s = time.perf_counter() - t0
 
-    t1 = time.perf_counter()
+    run_s = 0.0
     bsz = cfg.batch or cfg.instances
     n_batches = (cfg.instances + bsz - 1) // bsz
     chunks_per_batch = (cfg.ops + cfg.chunk - 1) // cfg.chunk
+    chunk_lengths = sorted({min(cfg.chunk, cfg.ops - s)
+                            for s in range(0, cfg.ops, cfg.chunk)})
     hb.configure(total_chunks=n_batches * chunks_per_batch,
                  total_ops=cfg.instances * cfg.ops)
+    t = fleet.template
     counts = np.zeros((cfg.instances, N_EV), dtype=np.int64)
     bails = residents = 0
     for s in range(0, cfg.instances, bsz):
         e = min(s + bsz, cfg.instances)
-        c, b, r = _run_batch(fleet.template, cfg, fleet.kinds[:, s:e],
-                             backend_name, devices, s, prof=prof, hb=hb)
+        t1 = time.perf_counter()
+        prof.push("lowering")
+        backend = _make_backend(backend_name, t,
+                                replicate(t.row, t.dims, e - s), devices)
+        backend.prepare(chunk_lengths)
+        prof.pop()
+        t2 = time.perf_counter()
+        c, b, r = _run_batch(t, cfg, fleet.kinds[:, s:e], backend,
+                             prof=prof, hb=hb)
+        build_s += t2 - t1
+        run_s += time.perf_counter() - t2
         counts[s:e] = c
         bails += b
         residents += r
-    run_s = time.perf_counter() - t1
     if heartbeat is not None:
         hb.emit(final=True)
     return FleetResult(cfg=cfg, backend=backend_name, devices=devices,
                        counts=counts, kinds=fleet.kinds, bails=bails,
                        residents=residents, build_s=build_s, run_s=run_s,
-                       template=fleet.template)
+                       template=fleet.template,
+                       device=(None if backend_name == "numpy"
+                               else device_info(devices)))
 
 
 def check_instances(result: FleetResult, sample: int = 8, seed: int = 1234,

@@ -33,10 +33,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
 
     def kv_step(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T                                   # (G, block_k)
         pos = base + j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (G, block_k), 1)

@@ -40,10 +40,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def kv_step(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T                                  # (block_q, block_k)
         if causal:
             k_pos = j * block_k + jax.lax.broadcasted_iota(

@@ -14,10 +14,13 @@ Bail flags come back through the ``active`` / ``bail_at`` state outputs
 -- the runner's poll/rejoin protocol is unchanged.  All state inputs are
 aliased to the outputs, so the chunk steps in place.
 
-On this container (CPU-only) the kernel runs with ``interpret=True``,
-which is also what CI's ``fleet-pallas-smoke`` job exercises; the
+The kernel runs with ``interpret=True`` on the CPU platform only, which
+is what CI's ``fleet-pallas-smoke`` job exercises; the
 ``tests/test_fleet_equivalence.py`` backend matrix gates bit-identity
-with ``run_batched`` either way.
+with ``run_batched``.  Mosaic refuses it for TPU (``dynamic_slice`` of
+the per-op index has no Pallas TPU lowering, nor do the vmapped
+per-instance gathers and scatters, ``argsort``/``cumsum`` or ``bool``
+refs behind it); ``tests/test_tpu_compile.py`` pins that refusal.
 """
 from __future__ import annotations
 

@@ -73,14 +73,18 @@ def build_manifest(subcommand: str,
                    headline: Optional[Dict[str, float]] = None,
                    phases: Optional[Dict[str, Dict[str, int]]] = None,
                    wall_s: Optional[float] = None,
-                   extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+                   extra: Optional[Dict[str, Any]] = None,
+                   device: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Assemble a schema-valid manifest dict.
 
     ``config`` is the resolved CLI namespace (seed included), ``metrics``
     the per-row measurements mirroring the CSV, ``headline`` the flat
     ``key -> number`` cells bench_history tracks, ``phases`` a
     ``PhaseProfiler.as_dict()``, ``extra`` free-form sections (e.g. the
-    paper-§8 post-flush attribution from `repro.trace.analyze`).
+    paper-§8 post-flush attribution from `repro.trace.analyze`),
+    ``device`` the JAX device a run's numbers came from --
+    ``{platform, kind, count}`` as ``jax.devices()`` reports it -- or
+    None when no JAX backend ran (host numbers).
     """
     man: Dict[str, Any] = {
         "schema": MANIFEST_SCHEMA,
@@ -94,6 +98,7 @@ def build_manifest(subcommand: str,
         "headline": dict(headline) if headline is not None else {},
         "phases": dict(phases) if phases is not None else None,
         "wall_s": wall_s,
+        "device": dict(device) if device is not None else None,
     }
     if extra:
         man.update(extra)
@@ -138,6 +143,14 @@ def validate_manifest(man: Any) -> Dict[str, Any]:
                         or "count" not in cell):
                     problems.append(
                         f"phases[{name!r}] must be a dict with ns+count")
+    device = man.get("device")
+    if device is not None and not (
+            isinstance(device, dict)
+            and isinstance(device.get("platform"), str)
+            and isinstance(device.get("kind"), str)
+            and isinstance(device.get("count"), int)):
+        problems.append("device must be None or a dict with str platform, "
+                        "str kind and int count")
     wall = man.get("wall_s")
     if wall is not None and not isinstance(wall, (int, float)):
         problems.append("wall_s must be a number or None")

@@ -185,8 +185,8 @@ def test_docs_name_the_burst_executor():
 def test_docs_name_the_fleet_backends():
     """Satellite: docs/fleet.md carries the backend matrix (all four
     `--backend` values, with the kernel source file), and
-    docs/observability.md names the Pallas phase constants exactly as
-    `repro.fleet.jaxexec.PallasBackend` reports them."""
+    docs/observability.md names the Pallas chunk phase exactly as
+    `repro.fleet.jaxexec.PallasBackend` reports it."""
     fleet = (REPO / "docs" / "fleet.md").read_text()
     for span in ("numpy", "jax-opcode", "pallas",
                  "src/repro/kernels/fleet_step.py",
@@ -194,9 +194,9 @@ def test_docs_name_the_fleet_backends():
         assert span in fleet, f"fleet.md does not mention {span}"
     obs = (REPO / "docs" / "observability.md").read_text()
     from repro.fleet.jaxexec import PallasBackend
-    for phase in (PallasBackend.PHASE_COMPILED, PallasBackend.PHASE_INTERPRET):
-        assert phase in obs, (
-            f"observability.md does not name the {phase!r} phase")
+    assert PallasBackend.chunk_phase in obs, (
+        f"observability.md does not name the {PallasBackend.chunk_phase!r} "
+        f"phase")
     assert "_wall_us_per_op" in obs, (
         "observability.md must document backend-qualified headline cells")
 

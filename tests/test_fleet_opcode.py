@@ -119,18 +119,6 @@ def _count_eqns(obj):
     return total
 
 
-def _state_dict(template, n):
-    from repro.fleet import jaxexec
-    state = replicate(template.row, template.dims, n)
-    st = {f: getattr(state, f) for f in jaxexec._ARRAY_FIELDS}
-    for f in jaxexec._SCALAR_FIELDS:
-        st[f] = getattr(state, f)
-    st["counts"] = state.counts.astype(np.int32)
-    for attr, arr in state.slots.items():
-        st["slot_" + attr] = arr
-    return st
-
-
 def _deepen(programs, k):
     """A synthetic deep schedule: the same programs with k copies of the
     micro sequence (still encodable and traceable -- semantics don't
@@ -144,10 +132,11 @@ def test_opcode_trace_size_independent_of_schedule_depth():
     """The acceptance bound: 8x deeper schedules leave the opcode chunk
     fn's jaxpr equation count unchanged, while the unrolled chunk fn's
     grows -- and on the deep variant the opcode trace is the smaller."""
-    from repro.fleet.jaxexec import make_chunk_fn, make_opcode_chunk_fn
+    from repro.fleet.jaxexec import (make_chunk_fn, make_opcode_chunk_fn,
+                                     state_arrays)
 
     t = build_template("DurableMSQ", "optane-clwb", ops=16)
-    st = _state_dict(t, 4)
+    st = state_arrays(replicate(t.row, t.dims, 4))
     kcols = np.zeros((4, 8), dtype=np.uint8)
     oi = np.arange(8, dtype=np.int32)
     deep = _deepen(t.programs, 8)

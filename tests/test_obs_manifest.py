@@ -44,6 +44,14 @@ def test_manifest_round_trip(tmp_path):
     assert back["wall_s"] == 1.25
 
 
+def test_manifest_records_the_jax_device(tmp_path):
+    """A run on a JAX backend names its device; a host run records none."""
+    assert _manifest()["device"] is None
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    out = write_manifest(_manifest(device=dev), tmp_path / "m.json")
+    assert load_manifest(out)["device"] == dev
+
+
 def test_manifest_path_convention(tmp_path):
     assert str(manifest_path_for("out/fleet.csv")).endswith(
         "out/fleet.manifest.json")
@@ -62,6 +70,8 @@ def test_manifest_extra_merges_top_level():
     lambda m: m.__setitem__("headline", {"k": "not-a-number"}),
     lambda m: m.__setitem__("metrics", "not-a-list"),
     lambda m: m.pop("subcommand"),
+    lambda m: m.__setitem__("device", {"platform": "tpu", "count": 1}),
+    lambda m: m.__setitem__("device", "tpu"),
 ])
 def test_manifest_validation_rejects_corruption(mutate):
     man = _manifest()
