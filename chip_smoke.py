@@ -22,11 +22,20 @@ Everything runs in this one process, which holds the chip.
 import argparse
 import json
 import sys
+import time
 from dataclasses import replace
 from pathlib import Path
 
 SRC = Path(__file__).resolve().parent / "src"
 OPS = 96
+T0 = time.perf_counter()
+
+
+def log(msg: str) -> None:
+    """A progress line on standard error, with the seconds since start,
+    so that a run stopped from outside shows how far it got."""
+    print(f"chip_smoke [{time.perf_counter() - T0:7.1f} s] {msg}",
+          file=sys.stderr, flush=True)
 
 
 def smoke_cell(queue: str, instances: int, devices: int) -> bool:
@@ -38,10 +47,14 @@ def smoke_cell(queue: str, instances: int, devices: int) -> bool:
     cfg = FleetConfig(queue=queue, model="optane-clwb", instances=instances,
                       ops=OPS, chunk=48, prefill=10, seed=0,
                       backend="jax-opcode", devices=devices)
+    log(f"{queue}: {instances} instances on jax-opcode")
     res = run_fleet(cfg)
+    log(f"{queue}: jax-opcode done (set-up {res.build_s:.1f} s, run "
+        f"{res.run_s:.1f} s); numpy reference")
     ref = run_fleet(replace(cfg, backend="numpy", devices=1),
                     kinds=res.kinds)
     counts_equal = bool(np.array_equal(res.counts, ref.counts))
+    log(f"{queue}: numpy done; 8 run_batched checks")
     checks = check_instances(res, sample=8)
     ok = sum(r["ok"] for r in checks)
     print(f"smoke {queue}: {instances} instances x {OPS} ops on "

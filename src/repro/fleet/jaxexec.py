@@ -1,13 +1,20 @@
-"""JAX backend: per-instance step fn, ``lax.scan`` over the op stream,
-``jax.vmap`` over the fleet, sharded across forced host devices.
+"""JAX backend: per-instance op functions, ``jax.vmap`` over the fleet,
+``lax.scan`` over the op stream, sharded over a mesh of devices.
 
-The step function is a straight functional transcription of
+The op functions are a straight functional transcription of
 :mod:`repro.fleet.stepper` for a *single* instance (scalars + small 1D
-arrays); ``jax.vmap`` batches it over the instance axis and ``lax.scan``
-drives it down a chunk of the op stream.  Both lowered programs run every
-step as masked straight-line code (no ``lax.cond`` -- the fleet's whole
-premise is that each op is a handful of gathers/scatters, so executing the
-non-selected program under a False mask is cheaper than divergence).
+arrays); :func:`_batch_op` batches one op over the instance axis and
+``lax.scan`` drives the batch down a chunk of the op stream.  Both
+lowered programs run every op as masked straight-line code (no
+per-instance ``lax.cond``: executing the non-selected program under a
+False mask is cheaper than divergence).
+
+Reads and writes at a per-instance index are one-hot selects, and the
+epoch advance compacts with shift networks: under ``vmap`` a gather,
+scatter or sort with one index per instance lowers to TPU code that
+grows with the instance count, and compiles for minutes at 100k
+instances.  The advance runs under a batch-level ``lax.cond``, only in
+steps where some instance advances.
 
 The instance axis is sharded over a 1D mesh of the first ``devices`` JAX
 devices (TPU chips; on the CPU platform, host devices, of which there
@@ -23,7 +30,7 @@ needs jax x64 mode.  Bit-identity with the numpy stepper (and hence with
 ``run_batched``) is asserted by ``tests/test_fleet_equivalence.py``.
 
 Two steppers share the sections that don't depend on schedule depth
-(:func:`_op_prologue`: tail record, bail detection, epoch machinery, env
+(:func:`_batch_op`: tail record, bail detection, epoch machinery, env
 binding + allocations):
 
 * the original **unrolled** stepper (:func:`_apply_one` /
@@ -39,6 +46,7 @@ binding + allocations):
 from __future__ import annotations
 
 import os
+from functools import partial
 from pathlib import Path
 
 import numpy as np
@@ -100,63 +108,148 @@ def state_arrays(state: FleetState) -> dict:
     return st
 
 
-def _advance_one(jnp, dims, c):
-    """Epoch advance for one instance (no-op when ``c['_adv']`` is False:
-    the freed mask is empty and the epoch increment is masked)."""
-    adv = c.pop("_adv")
+def _take(jnp, x, i):
+    """``x[i]`` for one instance's vector ``x`` and traced scalar ``i``,
+    with JAX's indexing rules (a negative index counts from the end, an
+    out-of-range one clamps), as a one-hot masked sum: under ``vmap`` a
+    gather with one index per instance, which the TPU compiler emits as
+    code that grows with the instance count."""
+    n = x.shape[0]
+    i = jnp.clip(jnp.where(i < 0, i + n, i), 0, n - 1)
+    return jnp.where(jnp.arange(n) == i, x, 0).sum(dtype=x.dtype)
+
+
+def _put(jnp, x, i, v):
+    """``x.at[i].set(v)`` for traced scalars, as a one-hot select (a
+    negative index counts from the end, an out-of-range one is dropped)."""
+    n = x.shape[0]
+    i = jnp.where(i < 0, i + n, i)
+    return jnp.where(jnp.arange(n) == i, jnp.asarray(v).astype(x.dtype), x)
+
+
+def _bump(jnp, x, i):
+    """``x.at[i].add(1)`` for a traced scalar, as a one-hot add."""
+    return x + (jnp.arange(x.shape[0]) == i).astype(x.dtype)
+
+
+def _shift(jnp, x, s):
+    """``y[p] = x[p + s]`` for a static ``s`` (negative: from the left),
+    zero where ``p + s`` is out of range."""
+    if s == 0:
+        return x
+    z = jnp.zeros((abs(s),), x.dtype)
+    if s > 0:
+        return jnp.concatenate([x[s:], z])
+    return jnp.concatenate([z, x[:s]])
+
+
+def _prefix_sum(jnp, x):
+    """Inclusive prefix sum of one instance's int32 vector, in log-step
+    shifted adds."""
+    s = 1
+    while s < x.shape[0]:
+        x = x + _shift(jnp, x, -s)
+        s *= 2
+    return x
+
+
+def _pack(jnp, xs, keep):
+    """Stable compaction of one instance's vectors: the ``keep`` entries
+    of every array in ``xs`` move to the front, in order.  Returns
+    ``(packed, occupied)``; the values of unoccupied slots are
+    unspecified.
+
+    A shift network, not a scatter: each kept entry moves left by the
+    number of dropped entries before it, one bit of that distance per
+    step, low bit first.  No two entries ever land on one slot: two kept
+    entries ``D`` apart differ by less than ``D`` in distance."""
+    d = jnp.where(keep, _prefix_sum(jnp, (~keep).astype(jnp.int32)), 0)
+    occ = keep
+    for b in range(keep.shape[0].bit_length()):
+        s = 1 << b
+        go = occ & (((d >> b) & 1) == 1)
+        came = _shift(jnp, go, s)
+        xs = [jnp.where(came, _shift(jnp, x, s), x) for x in xs]
+        d = jnp.where(came, _shift(jnp, d, s), d)
+        occ = (occ & ~go) | came
+    return xs, occ
+
+
+def _advance_one(jnp, dims, c, adv):
+    """Epoch advance for one instance (the identity when ``adv`` is False:
+    the freed mask is empty and the epoch increment is masked).  Freed
+    limbo entries are pushed, in order, onto the free stack of their kind
+    (past the stack's capacity they are dropped); the rest keep their
+    order at the front of the limbo list, and the freed and unused slots
+    follow in their old order -- a stable partition."""
     min_e = c["epoch"]
+    c = dict(c)
     c["epoch"] = jnp.where(adv, min_e + 1, min_e)
-    j = jnp.arange(dims.lcap, dtype=jnp.int32)
+    lcap = dims.lcap
+    j = jnp.arange(lcap, dtype=jnp.int32)
     inl = j < c["nlimbo"]
     fr = inl & (c["limbo_e"] + 2 <= min_e) & adv
     is_p = c["limbo_k"] == 0
     for sel, stack, nkey, slen in ((fr & is_p, "free_p", "nfree", dims.fcap),
                                    (fr & ~is_p, "vfree", "nvfree",
                                     dims.vfcap)):
-        cnt = jnp.cumsum(sel.astype(jnp.int32))
-        dest = jnp.where(sel, c[nkey] + cnt - 1, slen)   # OOB -> dropped
-        c[stack] = c[stack].at[dest].set(c["limbo_a"], mode="drop")
-        c[nkey] = c[nkey] + cnt[-1]
+        (vals,), occ = _pack(jnp, [c["limbo_a"]], sel)
+        width = max(lcap, slen)
+        if width > lcap:
+            pad = jnp.zeros((width - lcap,), jnp.int32)
+            vals = jnp.concatenate([vals, pad])
+            occ = jnp.concatenate([occ, pad.astype(bool)])
+        base = c[nkey]                      # the k-th freed entry -> base + k
+        for b in range(width.bit_length()):     # base <= slen <= width
+            on = ((base >> b) & 1) == 1
+            vals = jnp.where(on, _shift(jnp, vals, -(1 << b)), vals)
+            occ = jnp.where(on, _shift(jnp, occ, -(1 << b)), occ)
+        c[stack] = jnp.where(occ[:slen], vals[:slen], c[stack])
+        c[nkey] = base + sel.sum(dtype=jnp.int32)
     keep = inl & ~fr
-    order = jnp.argsort(jnp.where(keep, 0, 1).astype(jnp.int32), stable=True)
-    for key in ("limbo_a", "limbo_e", "limbo_k"):
-        c[key] = c[key][order]
-    c["nlimbo"] = c["nlimbo"] - fr.sum().astype(jnp.int32)
+    keys = ("limbo_a", "limbo_e", "limbo_k")
+    front, occ = _pack(jnp, [c[k] for k in keys], keep)
+    back, _ = _pack(jnp, [c[k][::-1] for k in keys], ~keep[::-1])
+    for key, f, b in zip(keys, front, back):
+        c[key] = jnp.where(occ, f, b[::-1])
+    c["nlimbo"] = c["nlimbo"] - fr.sum(dtype=jnp.int32)
     return c
 
 
-def _op_prologue(jnp, dims, prog, c, sel, oi):
-    """The depth-independent front half of one lowered op on one
-    instance's state dict: tail record, bail detection, op_begin epoch
-    machinery, env binding + allocations.  Shared verbatim by the
-    unrolled and opcode steppers; returns ``(c, m, env)``.
+def _tail(jnp, dims, c):
+    """(tail_p, tail_v): the newest node, or the dummy when empty."""
+    has = c["length"] > 0
+    tpos = (c["head"] + jnp.maximum(c["length"] - 1, 0)) % dims.cap
+    return (jnp.where(has, _take(jnp, c["ring_p"], tpos), c["dummy_p"]),
+            jnp.where(has, _take(jnp, c["ring_v"], tpos), c["dummy_v"]))
 
-    Guard-slot values are read through ``slot()`` so the same code serves
-    both state layouts: per-attr ``slot_<attr>`` keys (unrolled stepper)
-    and the stacked ``slots`` vector the opcode stepper gathers from."""
+
+def _slot(dims, c, attr):
+    """A guard slot's value, from either state layout: per-attr
+    ``slot_<attr>`` keys (unrolled stepper) or the stacked ``slots``
+    vector (opcode stepper)."""
+    if "slots" in c:
+        return c["slots"][dims.slot_attrs.index(attr)]
+    return c["slot_" + attr]
+
+
+def _op_begin(jnp, dims, prog, c, sel, oi):
+    """The front of one lowered op on one instance's state dict: bail
+    detection and op_begin's op counter.  Returns ``(c, m, adv)``: the
+    op's mask and whether this op advances the epoch."""
     c = dict(c)
     m = c["active"] & sel
-    cap = dims.cap
-    length, head = c["length"], c["head"]
-    has = length > 0
-    tpos = (head + jnp.maximum(length - 1, 0)) % cap
-    tail_p = jnp.where(has, c["ring_p"][tpos], c["dummy_p"])
-    tail_v = jnp.where(has, c["ring_v"][tpos], c["dummy_v"])
-
-    def slot(attr):
-        if "slots" in c:
-            return c["slots"][dims.slot_attrs.index(attr)]
-        return c["slot_" + attr]
-
+    tail_p, _ = _tail(jnp, dims, c)
     # ---- bail detection --------------------------------------------------
     bail = jnp.asarray(False)
     if prog.code == KIND_DEQ:
-        bail = bail | (length == 0)
+        bail = bail | (c["length"] == 0)
     for g in prog.guards:
         if g[0] == "slot_nonnull":
-            bail = bail | (slot(g[1]) == NULL)
+            bail = bail | (_slot(dims, c, g[1]) == NULL)
         else:                               # tail_persisted
-            bail = bail | (c["persisted"][tail_p // LINE_WORDS] == 0)
+            bail = bail | (_take(jnp, c["persisted"],
+                                 tail_p // LINE_WORDS) == 0)
     if prog.allocs_p:
         bail = bail | ((c["nfree"] == 0) & (c["cursor"] >= dims.area_cap))
     if prog.allocs_v:
@@ -166,44 +259,74 @@ def _op_prologue(jnp, dims, prog, c, sel, oi):
     c["active"] = c["active"] & ~newly
     m = m & ~newly
     # ---- op_begin --------------------------------------------------------
+    adv = jnp.asarray(False)
     if prog.uses_ssmem:
         ctr = c["opsctr"] + 1
         adv = m & (ctr >= EPOCH_ADV_OPS)
         c["opsctr"] = jnp.where(m, jnp.where(adv, 0, ctr), c["opsctr"])
-        c["_adv"] = adv
-        c = _advance_one(jnp, dims, c)
-    # ---- env + allocations ----------------------------------------------
+    return c, m, adv
+
+
+def _op_env(jnp, dims, prog, c, m):
+    """Env binding and allocations for one instance, after the epoch
+    advance; returns ``(c, env)``."""
+    c = dict(c)
     env = {}
     if prog.code == KIND_ENQ:
-        env[E_TAIL_P], env[E_TAIL_V] = tail_p, tail_v
+        env[E_TAIL_P], env[E_TAIL_V] = _tail(jnp, dims, c)
     else:
-        hpos = head % cap
+        hpos = c["head"] % dims.cap
         env[E_HEAD_P], env[E_HEAD_V] = c["dummy_p"], c["dummy_v"]
-        env[E_NEXT_P] = c["ring_p"][hpos]
-        env[E_NEXT_V] = c["ring_v"][hpos]
+        env[E_NEXT_P] = _take(jnp, c["ring_p"], hpos)
+        env[E_NEXT_V] = _take(jnp, c["ring_v"], hpos)
     for attr in prog.slot_attrs:
-        env[E_PREV] = slot(attr)
+        env[E_PREV] = _slot(dims, c, attr)
     if prog.allocs_p:
         use = c["nfree"] > 0
-        top = c["free_p"][jnp.maximum(c["nfree"] - 1, 0)]
+        top = _take(jnp, c["free_p"], jnp.maximum(c["nfree"] - 1, 0))
         env[E_NEW_P] = jnp.where(
             use, top, dims.area_base + c["cursor"] * LINE_WORDS)
         c["nfree"] = jnp.where(m & use, c["nfree"] - 1, c["nfree"])
         c["cursor"] = jnp.where(m & ~use, c["cursor"] + 1, c["cursor"])
     if prog.allocs_v:
         use = c["nvfree"] > 0
-        top = c["vfree"][jnp.maximum(c["nvfree"] - 1, 0)]
+        top = _take(jnp, c["vfree"], jnp.maximum(c["nvfree"] - 1, 0))
         env[E_NEW_V] = jnp.where(
             use, top, dims.chunk_base + c["vcursor"] * dims.node_words)
         c["nvfree"] = jnp.where(m & use, c["nvfree"] - 1, c["nvfree"])
         c["vcursor"] = jnp.where(m & ~use, c["vcursor"] + 1, c["vcursor"])
-    return c, m, env
+    return c, env
 
 
-def _apply_one(jnp, dims, prog, c, sel, oi):
-    """One lowered op on one instance's state dict, masked by ``sel``
-    (unrolled form: every micro/aux entry traces inline)."""
-    c, m, env = _op_prologue(jnp, dims, prog, c, sel, oi)
+def _batch_op(jax, dims, prog, st, sel, oi, apply):
+    """One lowered op across a batch of instances (leading axis), masked
+    by ``sel``: vmapped bail detection and op counter, the epoch advance,
+    then the vmapped env binding and ``apply(c, m, env) -> c``, the op's
+    depth-dependent body.  Shared by the unrolled and opcode steppers and
+    the Pallas kernel.
+
+    The advance is taken only in steps where some instance advances
+    (``lax.cond`` on the whole batch): under ``vmap`` its sort and
+    scatters would otherwise run, masked, on every op.  Skipping it when
+    no instance advances is exact, since it is the identity for those."""
+    jnp, lax = jax.numpy, jax.lax
+    st, m, adv = jax.vmap(partial(_op_begin, jnp, dims, prog),
+                          in_axes=(0, 0, None))(st, sel, oi)
+    if prog.uses_ssmem:
+        st = lax.cond(jnp.any(adv),
+                      jax.vmap(partial(_advance_one, jnp, dims)),
+                      lambda s, a: s, st, adv)
+
+    def finish(c, m):
+        c, env = _op_env(jnp, dims, prog, c, m)
+        return apply(c, m, env)
+    return jax.vmap(finish)(st, m)
+
+
+def _apply_one(jnp, dims, prog, c, m, env):
+    """The body of one lowered op on one instance's state dict, masked by
+    ``m``, after :func:`_op_env` (unrolled form: every micro/aux entry
+    traces inline)."""
     # ---- micro-ops on local copies --------------------------------------
     cached, finval, everfl = c["cached"], c["finval"], c["everfl"]
     vtouched, persisted = c["vtouched"], c["persisted"]
@@ -214,33 +337,34 @@ def _apply_one(jnp, dims, prog, c, sel, oi):
         a = ref.const if ref.mode == "const" else env[ref.sym] + ref.off
         if tag == "class_p":
             ln = a // LINE_WORDS
-            ev = jnp.where(cached[ln] == 1, EV_HIT,
-                           jnp.where(finval[ln] == 1, EV_POSTFLUSH,
-                                     jnp.where(everfl[ln] == 1, EV_COLD_NVM,
-                                               EV_COLD_DRAM)))
-            cdelta = cdelta.at[ev].add(1)
-            cached = cached.at[ln].set(one)
-            finval = finval.at[ln].set(zero)
+            ev = jnp.where(_take(jnp, cached, ln) == 1, EV_HIT,
+                           jnp.where(_take(jnp, finval, ln) == 1,
+                                     EV_POSTFLUSH,
+                                     jnp.where(_take(jnp, everfl, ln) == 1,
+                                               EV_COLD_NVM, EV_COLD_DRAM)))
+            cdelta = _bump(jnp, cdelta, ev)
+            cached = _put(jnp, cached, ln, one)
+            finval = _put(jnp, finval, ln, zero)
         elif tag == "class_v":
-            ev = jnp.where(vtouched[a] == 1, EV_HIT, EV_DRAM)
-            cdelta = cdelta.at[ev].add(1)
-            vtouched = vtouched.at[a].set(one)
+            ev = jnp.where(_take(jnp, vtouched, a) == 1, EV_HIT, EV_DRAM)
+            cdelta = _bump(jnp, cdelta, ev)
+            vtouched = _put(jnp, vtouched, a, one)
         elif tag == "state":
             ln = a // LINE_WORDS
             mode = ins[2]
             if mode == ST_INVAL:
-                cached = cached.at[ln].set(zero)
-                finval = finval.at[ln].set(one)
-                everfl = everfl.at[ln].set(one)
+                cached = _put(jnp, cached, ln, zero)
+                finval = _put(jnp, finval, ln, one)
+                everfl = _put(jnp, everfl, ln, one)
             elif mode == ST_EVERFL:
-                everfl = everfl.at[ln].set(one)
+                everfl = _put(jnp, everfl, ln, one)
             else:                           # ST_RECACHE
-                cached = cached.at[ln].set(one)
-                finval = finval.at[ln].set(zero)
+                cached = _put(jnp, cached, ln, one)
+                finval = _put(jnp, finval, ln, zero)
         else:                               # "line"
             ln = a // LINE_WORDS
-            cached = cached.at[ln].set(one)
-            finval = finval.at[ln].set(zero)
+            cached = _put(jnp, cached, ln, one)
+            finval = _put(jnp, finval, ln, zero)
     c["counts"] = jnp.where(m, c["counts"] + cdelta, c["counts"])
     # ---- logical FIFO ----------------------------------------------------
     cap = dims.cap
@@ -249,9 +373,9 @@ def _apply_one(jnp, dims, prog, c, sel, oi):
         pos = (head + length) % cap
         new_p = env[E_NEW_P] if prog.allocs_p else jnp.int32(0)
         new_v = env[E_NEW_V] if prog.allocs_v else jnp.int32(0)
-        c["ring_p"] = jnp.where(m, c["ring_p"].at[pos].set(new_p),
+        c["ring_p"] = jnp.where(m, _put(jnp, c["ring_p"], pos, new_p),
                                 c["ring_p"])
-        c["ring_v"] = jnp.where(m, c["ring_v"].at[pos].set(new_v),
+        c["ring_v"] = jnp.where(m, _put(jnp, c["ring_v"], pos, new_v),
                                 c["ring_v"])
         c["length"] = jnp.where(m, length + 1, length)
     else:
@@ -266,20 +390,22 @@ def _apply_one(jnp, dims, prog, c, sel, oi):
     for ax in prog.aux:
         t0 = ax[0]
         if t0 == "limbo":
-            limbo_a = limbo_a.at[nlimbo].set(env[ax[1]])
-            limbo_e = limbo_e.at[nlimbo].set(c["epoch"])
-            limbo_k = limbo_k.at[nlimbo].set(
-                jnp.uint8(0 if ax[2] == "p" else 1))
+            limbo_a = _put(jnp, limbo_a, nlimbo, env[ax[1]])
+            limbo_e = _put(jnp, limbo_e, nlimbo, c["epoch"])
+            limbo_k = _put(jnp, limbo_k, nlimbo,
+                           jnp.uint8(0 if ax[2] == "p" else 1))
             nlimbo = nlimbo + 1
             touched_limbo = True
         elif t0 == "slot":
             key = "slot_" + ax[1]
             c[key] = jnp.where(m, env[ax[2]], c[key])
         elif t0 == "pdiscard":
-            persisted = persisted.at[env[ax[1]] // LINE_WORDS].set(zero)
+            persisted = _put(jnp, persisted, env[ax[1]] // LINE_WORDS,
+                             zero)
         else:                               # padd
             for sym in ax[1]:
-                persisted = persisted.at[env[sym] // LINE_WORDS].set(one)
+                persisted = _put(jnp, persisted, env[sym] // LINE_WORDS,
+                                 one)
     if touched_limbo:
         c["limbo_a"] = jnp.where(m, limbo_a, c["limbo_a"])
         c["limbo_e"] = jnp.where(m, limbo_e, c["limbo_e"])
@@ -294,42 +420,65 @@ def _apply_one(jnp, dims, prog, c, sel, oi):
     return c
 
 
-def make_chunk_fn(jax, programs, dims):
-    """-> chunk(st, kcols, oi): vmap over instances of a lax.scan over the
-    chunk's op stream.  ``kcols`` is (N, C) uint8, ``oi`` (C,) int32 global
-    op indices (shared across instances)."""
-    import jax.numpy as jnp
-    from jax import lax
+def _scan_chunk(jax, st, kcols, oi, step_op):
+    """``lax.scan`` over the chunk's op stream of ``step_op(st, k, o)``,
+    a whole-batch step.  ``kcols`` is (N, C) uint8, ``oi`` (C,) int32
+    global op indices (shared across instances)."""
+    def step(st, xs):
+        return step_op(st, *xs), None
+    out, _ = jax.lax.scan(step, st, (kcols.T, oi))
+    return out
 
-    def per_instance(c, kcol, oi):
-        def step(carry, xs):
-            k, o = xs
-            for prog in programs:
-                carry = _apply_one(jnp, dims, prog, carry, k == prog.code, o)
-            return carry, None
-        out, _ = lax.scan(step, c, (kcol, oi))
-        return out
+
+def _per_device(jax, chunk, mesh):
+    """``chunk`` on each device's own rows of the instance axis
+    (``shard_map`` over the mesh axis ``"i"``; ``mesh=None`` leaves it to
+    the compiler).  Instances are independent, so each device steps its
+    rows, and takes the epoch advance's batch-level ``lax.cond`` on them,
+    with no collective."""
+    if mesh is None:
+        return chunk
+    from jax.sharding import PartitionSpec
+    rows = PartitionSpec("i")
+    # check_vma off: lax.switch would reject branches that differ only
+    # in which values vary across devices (a constant count vector
+    # against one updated from the rows)
+    return jax.shard_map(chunk, mesh=mesh,
+                         in_specs=(rows, rows, PartitionSpec()),
+                         out_specs=rows, check_vma=False)
+
+
+def make_chunk_fn(jax, programs, dims, mesh=None):
+    """-> chunk(st, kcols, oi): a lax.scan over the chunk's op stream,
+    each op vmapped over the instances (:func:`_batch_op`), per device
+    of ``mesh`` (:func:`_per_device`)."""
+    import jax.numpy as jnp
+
+    def step_op(st, k, o):
+        for prog in programs:
+            st = _batch_op(jax, dims, prog, st, k == prog.code, o,
+                           partial(_apply_one, jnp, dims, prog))
+        return st
 
     def chunk(st, kcols, oi):
-        return jax.vmap(per_instance, in_axes=(0, 0, None))(st, kcols, oi)
+        return _scan_chunk(jax, st, kcols, oi, step_op)
 
-    return chunk
+    return _per_device(jax, chunk, mesh)
 
 
-def _apply_opcode_one(jnp, lax, dims, prog, opc, c, sel, oi,
+def _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env,
                       table=None, base_counts=None):
-    """One lowered op on one instance's state dict, masked by ``sel`` --
-    data-driven form: a ``fori_loop`` + ``switch`` interprets the int32
-    opcode table instead of tracing each micro/aux entry, so the jaxpr
-    does not grow with schedule depth.  Requires the stacked ``slots``
-    state layout (see :func:`make_opcode_chunk_fn`).  Bit-identical to
-    :func:`_apply_one`: same prologue, same effect order, same masked
-    commits.
+    """The body of one lowered op on one instance's state dict, masked by
+    ``m``, after :func:`_op_env` -- data-driven form: a ``fori_loop`` +
+    ``switch`` interprets the int32 opcode table instead of tracing each
+    micro/aux entry, so the jaxpr does not grow with schedule depth.
+    Requires the stacked ``slots`` state layout (see
+    :func:`make_opcode_chunk_fn`).  Bit-identical to :func:`_apply_one`:
+    same effect order, same masked commits.
 
     ``table`` / ``base_counts`` default to trace constants from
     ``opc`` / ``prog``; the Pallas kernel passes them explicitly (a
     kernel cannot capture array constants)."""
-    c, m, env = _op_prologue(jnp, dims, prog, c, sel, oi)
     # dense env vector for data-driven sym gathers (static keys)
     envv = jnp.zeros((N_SYM,), jnp.int32)
     for k, v in env.items():
@@ -356,54 +505,54 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, sel, oi,
 
         def b_class_p(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            ev = jnp.where(ca[ln] == 1, EV_HIT,
-                           jnp.where(fi[ln] == 1, EV_POSTFLUSH,
-                                     jnp.where(ev_[ln] == 1, EV_COLD_NVM,
-                                               EV_COLD_DRAM)))
-            return (ca.at[ln].set(one), fi.at[ln].set(zero), ev_, vt, pe,
-                    la, le, lk, nl, sl, cd.at[ev].add(1))
+            ev = jnp.where(_take(jnp, ca, ln) == 1, EV_HIT,
+                           jnp.where(_take(jnp, fi, ln) == 1, EV_POSTFLUSH,
+                                     jnp.where(_take(jnp, ev_, ln) == 1,
+                                               EV_COLD_NVM, EV_COLD_DRAM)))
+            return (_put(jnp, ca, ln, one), _put(jnp, fi, ln, zero), ev_,
+                    vt, pe, la, le, lk, nl, sl, _bump(jnp, cd, ev))
 
         def b_class_v(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            ev = jnp.where(vt[a] == 1, EV_HIT, EV_DRAM)
-            return (ca, fi, ev_, vt.at[a].set(one), pe, la, le, lk, nl, sl,
-                    cd.at[ev].add(1))
+            ev = jnp.where(_take(jnp, vt, a) == 1, EV_HIT, EV_DRAM)
+            return (ca, fi, ev_, _put(jnp, vt, a, one), pe, la, le, lk, nl,
+                    sl, _bump(jnp, cd, ev))
 
         def b_st_inval(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca.at[ln].set(zero), fi.at[ln].set(one),
-                    ev_.at[ln].set(one), vt, pe, la, le, lk, nl, sl, cd)
+            return (_put(jnp, ca, ln, zero), _put(jnp, fi, ln, one),
+                    _put(jnp, ev_, ln, one), vt, pe, la, le, lk, nl, sl, cd)
 
         def b_st_everfl(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_.at[ln].set(one), vt, pe, la, le, lk, nl,
-                    sl, cd)
+            return (ca, fi, _put(jnp, ev_, ln, one), vt, pe, la, le, lk,
+                    nl, sl, cd)
 
         def b_recache(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca.at[ln].set(one), fi.at[ln].set(zero), ev_, vt, pe,
-                    la, le, lk, nl, sl, cd)
+            return (_put(jnp, ca, ln, one), _put(jnp, fi, ln, zero), ev_,
+                    vt, pe, la, le, lk, nl, sl, cd)
 
         def b_limbo(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe, la.at[nl].set(a),
-                    le.at[nl].set(epoch), lk.at[nl].set(imm.astype(lk.dtype)),
+            return (ca, fi, ev_, vt, pe, _put(jnp, la, nl, a),
+                    _put(jnp, le, nl, epoch), _put(jnp, lk, nl, imm),
                     nl + 1, sl, cd)
 
         def b_slot(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe, la, le, lk, nl, sl.at[imm].set(a),
-                    cd)
+            return (ca, fi, ev_, vt, pe, la, le, lk, nl,
+                    _put(jnp, sl, imm, a), cd)
 
         def b_pdiscard(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe.at[ln].set(zero), la, le, lk, nl,
-                    sl, cd)
+            return (ca, fi, ev_, vt, _put(jnp, pe, ln, zero), la, le, lk,
+                    nl, sl, cd)
 
         def b_padd(t):
             (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe.at[ln].set(one), la, le, lk, nl,
-                    sl, cd)
+            return (ca, fi, ev_, vt, _put(jnp, pe, ln, one), la, le, lk,
+                    nl, sl, cd)
 
         branches = [b_nop] * N_OPC
         branches[OPC_CLASS_P] = b_class_p
@@ -429,9 +578,9 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, sel, oi,
         pos = (head + length) % cap
         new_p = env[E_NEW_P] if prog.allocs_p else jnp.int32(0)
         new_v = env[E_NEW_V] if prog.allocs_v else jnp.int32(0)
-        c["ring_p"] = jnp.where(m, c["ring_p"].at[pos].set(new_p),
+        c["ring_p"] = jnp.where(m, _put(jnp, c["ring_p"], pos, new_p),
                                 c["ring_p"])
-        c["ring_v"] = jnp.where(m, c["ring_v"].at[pos].set(new_v),
+        c["ring_v"] = jnp.where(m, _put(jnp, c["ring_v"], pos, new_v),
                                 c["ring_v"])
         c["length"] = jnp.where(m, length + 1, length)
     else:
@@ -452,26 +601,23 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, sel, oi,
     return c
 
 
-def make_opcode_chunk_fn(jax, programs, dims):
+def make_opcode_chunk_fn(jax, programs, dims, mesh=None):
     """Opcode-interpreting variant of :func:`make_chunk_fn` -- same
     ``chunk(st, kcols, oi)`` signature and the same state-dict layout
     outside the call (``slot_<attr>`` keys are stacked into a ``slots``
-    matrix around the vmapped scan).  The jit trace holds one
-    ``row_step`` body per op kind regardless of schedule depth."""
+    matrix around the scan).  The jit trace holds one ``row_step`` body
+    per op kind regardless of schedule depth."""
     import jax.numpy as jnp
     from jax import lax
 
     progs = [(p, encode_program(p, dims.slot_attrs)) for p in programs]
 
-    def per_instance(c, kcol, oi):
-        def step(carry, xs):
-            k, o = xs
-            for prog, opc in progs:
-                carry = _apply_opcode_one(jnp, lax, dims, prog, opc, carry,
-                                          k == prog.code, o)
-            return carry, None
-        out, _ = lax.scan(step, c, (kcol, oi))
-        return out
+    def step_op(st, k, o):
+        for prog, opc in progs:
+            st = _batch_op(jax, dims, prog, st, k == prog.code, o,
+                           partial(_apply_opcode_one, jnp, lax, dims, prog,
+                                   opc))
+        return st
 
     def chunk(st, kcols, oi):
         st = dict(st)
@@ -480,13 +626,13 @@ def make_opcode_chunk_fn(jax, programs, dims):
                 [st.pop("slot_" + a) for a in dims.slot_attrs], axis=-1)
         else:
             st["slots"] = jnp.zeros((kcols.shape[0], 1), jnp.int32)
-        out = jax.vmap(per_instance, in_axes=(0, 0, None))(st, kcols, oi)
+        out = _scan_chunk(jax, st, kcols, oi, step_op)
         slots = out.pop("slots")
         for i, a in enumerate(dims.slot_attrs):
             out["slot_" + a] = slots[:, i]
         return out
 
-    return chunk
+    return _per_device(jax, chunk, mesh)
 
 
 class JaxBackend:
@@ -502,7 +648,7 @@ class JaxBackend:
         self.t = template
         self.n = state.n
         self.devices = devices
-        mesh = Mesh(np.array(jax.devices()[:devices]), ("i",))
+        self.mesh = mesh = Mesh(np.array(jax.devices()[:devices]), ("i",))
         self.sharding = NamedSharding(mesh, PartitionSpec("i"))
         self.replicated = NamedSharding(mesh, PartitionSpec())
         self.npad = self._padded(state.n)
@@ -528,7 +674,7 @@ class JaxBackend:
 
     def _make_fn(self):
         return self.jax.jit(make_chunk_fn(self.jax, self.t.programs,
-                                          self.t.dims),
+                                          self.t.dims, self.mesh),
                             donate_argnums=(0,))
 
     def _compiled(self, C: int):
@@ -603,7 +749,7 @@ class OpcodeJaxBackend(JaxBackend):
 
     def _make_fn(self):
         return self.jax.jit(make_opcode_chunk_fn(self.jax, self.t.programs,
-                                                 self.t.dims),
+                                                 self.t.dims, self.mesh),
                             donate_argnums=(0,))
 
 

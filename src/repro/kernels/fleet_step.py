@@ -5,7 +5,8 @@ a whole chunk of the op stream.  The grid tiles the (padded) instance
 axis into blocks; each program id owns one block of rows from every state
 array, loops over the chunk's ops with ``lax.fori_loop`` and applies the
 *same* opcode interpreter the jax-opcode backend scans with
-(:func:`repro.fleet.jaxexec._apply_opcode_one`, vmapped over the block).
+(:func:`repro.fleet.jaxexec._apply_opcode_one`, vmapped over the block
+by :func:`repro.fleet.jaxexec._batch_op`).
 Sharing the interpreter is the point: the kernel adds a memory layout
 (explicit per-block refs, one launch per chunk instead of one dispatch
 per op), not a second semantics to keep bit-identical.
@@ -18,16 +19,16 @@ The kernel runs with ``interpret=True`` on the CPU platform only, which
 is what CI's ``fleet-pallas-smoke`` job exercises; the
 ``tests/test_fleet_equivalence.py`` backend matrix gates bit-identity
 with ``run_batched``.  Mosaic refuses it for TPU (``dynamic_slice`` of
-the per-op index has no Pallas TPU lowering, nor do the vmapped
-per-instance gathers and scatters, ``argsort``/``cumsum`` or ``bool``
-refs behind it); ``tests/test_tpu_compile.py`` pins that refusal.
+the per-op index has no Pallas TPU lowering; the ``switch`` per opcode
+row and the ``bool`` refs are behind it); ``tests/test_tpu_compile.py``
+pins that refusal.
 """
 from __future__ import annotations
 
 from functools import partial
 
 from ..fleet.jaxexec import (_ARRAY_FIELDS, _SCALAR_FIELDS,
-                             _apply_opcode_one)
+                             _apply_opcode_one, _batch_op)
 from ..fleet.lowering import encode_program
 
 # state-dict keys in ref order; "slots" is the stacked guard-slot matrix
@@ -56,10 +57,9 @@ def make_pallas_chunk_fn(jax, programs, dims, block: int = 128,
     n_const = len(const_arrays)
 
     def apply_block(st, k, o, prog, opc, table, bc):
-        return jax.vmap(
-            partial(_apply_opcode_one, jnp, lax, dims, prog, opc),
-            in_axes=(0, 0, None, None, None))(st, k == prog.code, o,
-                                              table, bc)
+        return _batch_op(jax, dims, prog, st, k == prog.code, o,
+                         partial(_apply_opcode_one, jnp, lax, dims, prog,
+                                 opc, table=table, base_counts=bc))
 
     def kernel(kc_ref, oi_ref, *refs):
         consts = [r[...] for r in refs[:n_const]]
