@@ -16,6 +16,12 @@ grows with the instance count, and compiles for minutes at 100k
 instances.  The advance runs under a batch-level ``lax.cond``, only in
 steps where some instance advances.
 
+The compiled step is the XLA module ``jit_chunk``; its ops carry named
+scopes in their HLO metadata (``op-enq``, ``op-deq``, ``epoch-advance``,
+``slots-stack``, ``slots-unstack``: ``repro.obs.profiler.PH_FLEET_*``),
+and the backend records native spans around its host work (plan packing
+and upload, dispatch, polls, the counts' readback, state upload, compile).
+
 The instance axis is sharded over a 1D mesh of the first ``devices`` JAX
 devices (TPU chips; on the CPU platform, host devices, of which there
 are more only where the caller's environment sets ``XLA_FLAGS=
@@ -46,6 +52,7 @@ binding + allocations):
 from __future__ import annotations
 
 import os
+from collections import deque
 from functools import partial
 from pathlib import Path
 
@@ -54,6 +61,12 @@ import numpy as np
 from ..core.nvram import (EV_COLD_DRAM, EV_COLD_NVM, EV_DRAM, EV_HIT,
                           EV_POSTFLUSH, LINE_WORDS)
 from ..core.opsched import NULL, ST_EVERFL, ST_INVAL
+from ..obs.profiler import (
+    PH_FLEET_ADVANCE, PH_FLEET_COMPILE, PH_FLEET_COUNTS_READBACK,
+    PH_FLEET_COUNTS_WAIT, PH_FLEET_KERNEL, PH_FLEET_OP_DEQ, PH_FLEET_OP_ENQ,
+    PH_FLEET_PLAN_PACK, PH_FLEET_PLAN_UPLOAD, PH_FLEET_POLL_READBACK,
+    PH_FLEET_POLL_WAIT, PH_FLEET_SLOTS_STACK, PH_FLEET_SLOTS_UNSTACK,
+    PH_FLEET_STATE_UPLOAD, PH_FLEET_STEP_DISPATCH, push, span)
 from .lowering import (KIND_DEQ, KIND_ENQ, SYM, N_OPC, OPC_CLASS_P,
                        OPC_CLASS_V, OPC_LIMBO, OPC_PADD, OPC_PDISCARD,
                        OPC_RECACHE, OPC_SLOT, OPC_ST_EVERFL, OPC_ST_INVAL,
@@ -76,6 +89,21 @@ _ARRAY_FIELDS = ("cached", "finval", "everfl", "persisted", "vtouched",
 _SCALAR_FIELDS = ("head", "length", "dummy_p", "dummy_v", "nfree", "cursor",
                   "nvfree", "vcursor", "nlimbo", "epoch", "opsctr",
                   "active", "bail_at")
+
+# each op program's named scope in the compiled step, by FleetProgram.code
+_OP_SCOPE = {KIND_ENQ: PH_FLEET_OP_ENQ, KIND_DEQ: PH_FLEET_OP_DEQ}
+
+# the newest compiled chunk steps of this process, for readers of a device
+# trace that map an op back to its named scope (:func:`compiled_steps`);
+# bounded, since a test process builds many backends
+_COMPILED_STEPS = deque(maxlen=4)
+
+
+def compiled_steps() -> list:
+    """The process's newest compiled chunk steps (``jax.stages.Compiled``),
+    oldest first; ``as_text()`` of one is its optimized HLO, whose
+    ``op_name`` metadata carries the step's named scopes."""
+    return list(_COMPILED_STEPS)
 
 
 # the checkout's root: src/repro/fleet/jaxexec.py -> parents[3]
@@ -313,9 +341,10 @@ def _batch_op(jax, dims, prog, st, sel, oi, apply):
     st, m, adv = jax.vmap(partial(_op_begin, jnp, dims, prog),
                           in_axes=(0, 0, None))(st, sel, oi)
     if prog.uses_ssmem:
-        st = lax.cond(jnp.any(adv),
-                      jax.vmap(partial(_advance_one, jnp, dims)),
-                      lambda s, a: s, st, adv)
+        with jax.named_scope(PH_FLEET_ADVANCE):
+            st = lax.cond(jnp.any(adv),
+                          jax.vmap(partial(_advance_one, jnp, dims)),
+                          lambda s, a: s, st, adv)
 
     def finish(c, m):
         c, env = _op_env(jnp, dims, prog, c, m)
@@ -456,8 +485,9 @@ def make_chunk_fn(jax, programs, dims, mesh=None):
 
     def step_op(st, k, o):
         for prog in programs:
-            st = _batch_op(jax, dims, prog, st, k == prog.code, o,
-                           partial(_apply_one, jnp, dims, prog))
+            with jax.named_scope(_OP_SCOPE[prog.code]):
+                st = _batch_op(jax, dims, prog, st, k == prog.code, o,
+                               partial(_apply_one, jnp, dims, prog))
         return st
 
     def chunk(st, kcols, oi):
@@ -614,22 +644,25 @@ def make_opcode_chunk_fn(jax, programs, dims, mesh=None):
 
     def step_op(st, k, o):
         for prog, opc in progs:
-            st = _batch_op(jax, dims, prog, st, k == prog.code, o,
-                           partial(_apply_opcode_one, jnp, lax, dims, prog,
-                                   opc))
+            with jax.named_scope(_OP_SCOPE[prog.code]):
+                st = _batch_op(jax, dims, prog, st, k == prog.code, o,
+                               partial(_apply_opcode_one, jnp, lax, dims,
+                                       prog, opc))
         return st
 
     def chunk(st, kcols, oi):
         st = dict(st)
-        if dims.slot_attrs:
-            st["slots"] = jnp.stack(
-                [st.pop("slot_" + a) for a in dims.slot_attrs], axis=-1)
-        else:
-            st["slots"] = jnp.zeros((kcols.shape[0], 1), jnp.int32)
+        with jax.named_scope(PH_FLEET_SLOTS_STACK):
+            if dims.slot_attrs:
+                st["slots"] = jnp.stack(
+                    [st.pop("slot_" + a) for a in dims.slot_attrs], axis=-1)
+            else:
+                st["slots"] = jnp.zeros((kcols.shape[0], 1), jnp.int32)
         out = _scan_chunk(jax, st, kcols, oi, step_op)
-        slots = out.pop("slots")
-        for i, a in enumerate(dims.slot_attrs):
-            out["slot_" + a] = slots[:, i]
+        with jax.named_scope(PH_FLEET_SLOTS_UNSTACK):
+            slots = out.pop("slots")
+            for i, a in enumerate(dims.slot_attrs):
+                out["slot_" + a] = slots[:, i]
         return out
 
     return _per_device(jax, chunk, mesh)
@@ -661,8 +694,10 @@ class JaxBackend:
                 a = np.concatenate([a, tile], axis=0)
             return self._put(a)
 
-        self.st = {name: put(name, a)
-                   for name, a in state_arrays(state).items()}
+        arrays = state_arrays(state)
+        nbytes = self.npad * sum(a.nbytes // self.n for a in arrays.values())
+        with span(PH_FLEET_STATE_UPLOAD, bytes=nbytes):
+            self.st = {name: put(name, a) for name, a in arrays.items()}
         self._fn = self._make_fn()
         self._exe = {}
 
@@ -689,7 +724,9 @@ class JaxBackend:
                                       sharding=self.sharding)
             oi = jax.ShapeDtypeStruct((C,), np.int32,
                                       sharding=self.replicated)
-            self._exe[C] = self._fn.lower(st, kc, oi).compile()
+            with span(PH_FLEET_COMPILE, C=C):
+                self._exe[C] = self._fn.lower(st, kc, oi).compile()
+            _COMPILED_STEPS.append(self._exe[C])
         return self._exe[C]
 
     def prepare(self, chunk_lengths) -> None:
@@ -698,17 +735,29 @@ class JaxBackend:
 
     def run_chunk(self, kinds: np.ndarray, start: int) -> None:
         C = kinds.shape[0]
-        kc = np.zeros((self.npad, C), dtype=np.uint8)
-        kc[:self.n] = kinds.T
-        oi = np.arange(start, start + C, dtype=np.int32)
-        self.st = self._compiled(C)(
-            self.st, self._put(kc), self.jax.device_put(oi, self.replicated))
+        with span(PH_FLEET_PLAN_PACK, start=start):
+            kc = np.zeros((self.npad, C), dtype=np.uint8)
+            kc[:self.n] = kinds.T
+            oi = np.arange(start, start + C, dtype=np.int32)
+        with span(PH_FLEET_PLAN_UPLOAD, start=start,
+                  bytes=kc.nbytes + oi.nbytes):
+            kc = self._put(kc)
+            oi = self.jax.device_put(oi, self.replicated)
+        step = self._compiled(C)
+        with span(PH_FLEET_STEP_DISPATCH, start=start):
+            self.st = step(self.st, kc, oi)
 
     def poll(self):
-        bail_at = np.asarray(self.st["bail_at"])[:self.n]
-        active = np.asarray(self.st["active"])[:self.n]
-        fresh = (~active) & (bail_at >= 0)
-        return np.nonzero(fresh)[0], bail_at
+        bail_at, active = self.st["bail_at"], self.st["active"]
+        with span(PH_FLEET_POLL_WAIT):
+            self.jax.block_until_ready((bail_at, active))
+        with span(PH_FLEET_POLL_READBACK,
+                  bytes=bail_at.nbytes + active.nbytes):
+            bail_at = np.asarray(bail_at)[:self.n]
+            active = np.asarray(active)[:self.n]
+            fresh = (~active) & (bail_at >= 0)
+            ids = np.nonzero(fresh)[0]
+        return ids, bail_at
 
     def _set_rows(self, i: int, values: dict) -> None:
         st = dict(self.st)
@@ -736,8 +785,13 @@ class JaxBackend:
         self._set_rows(i, {"active": False, "bail_at": RESIDENT})
 
     def counts(self) -> np.ndarray:
-        self.jax.block_until_ready(self.st)
-        return np.asarray(self.st["counts"])[:self.n].astype(np.int64)
+        """The counts on the host.  Opens the ``counts-readback`` span,
+        which the caller closes (``pop``) once it has merged them."""
+        with span(PH_FLEET_COUNTS_WAIT):
+            self.jax.block_until_ready(self.st)
+        counts = self.st["counts"]
+        push(PH_FLEET_COUNTS_READBACK, bytes=counts.nbytes)
+        return np.asarray(counts)[:self.n].astype(np.int64)
 
 
 class OpcodeJaxBackend(JaxBackend):
@@ -763,7 +817,7 @@ class PallasBackend(JaxBackend):
     Single-device: the grid replaces the mesh sharding of the base
     backend."""
     name = "pallas"
-    chunk_phase = "kernel-interpret"
+    chunk_phase = PH_FLEET_KERNEL
     block = 128
 
     def __init__(self, template: Template, state: FleetState,

@@ -36,6 +36,9 @@ import numpy as np
 
 from ..core.harness import ALL_QUEUES
 from ..core.nvram import N_EV, Stats
+from ..obs.profiler import (PH_FLEET_BAIL, PH_FLEET_CHUNK,
+                            PH_FLEET_COUNTS_READBACK, PH_FLEET_LOWER,
+                            PH_FLEET_POLL, PH_FLEET_RESIDENT, pop, push)
 from .state import (DEFAULT_PREFILL, Template, area_nodes_for, build_template,
                     export_instance, make_instance_harness, replicate)
 from .stepper import run_chunk_numpy
@@ -164,6 +167,9 @@ class NumpyBackend:
         self.st.bail_at[i] = RESIDENT
 
     def counts(self) -> np.ndarray:
+        """The counts (on the host already).  Opens the ``counts-readback``
+        span, which the caller closes once it has merged them."""
+        push(PH_FLEET_COUNTS_READBACK)
         return self.st.counts
 
 
@@ -284,19 +290,19 @@ def _run_batch(template: Template, cfg: FleetConfig, kinds: np.ndarray,
     n = kinds.shape[1]
     resident_counts = {}
     bails = residents = 0
-    chunk_phase = getattr(backend, "chunk_phase", "chunk-step")
+    chunk_phase = getattr(backend, "chunk_phase", PH_FLEET_CHUNK)
     for start in range(0, cfg.ops, cfg.chunk):
         end = min(start + cfg.chunk, cfg.ops)
         prof.push(chunk_phase)
         backend.run_chunk(kinds[start:end], start)
         prof.pop()
-        prof.push("poll")
+        prof.push(PH_FLEET_POLL)
         ids, _ = backend.poll()
         prof.pop()
         rejoins = 0
         for i in ids.tolist():
             bails += 1
-            prof.push("bail-replay")
+            prof.push(PH_FLEET_BAIL)
             h = _replay(template, kinds, i, end)
             row = export_instance(h, template.dims)
             if row is not None:
@@ -306,7 +312,7 @@ def _run_batch(template: Template, cfg: FleetConfig, kinds: np.ndarray,
             else:
                 prof.pop()
                 residents += 1
-                prof.push("resident-replay")
+                prof.push(PH_FLEET_RESIDENT)
                 rest = plan_of(kinds, i, end, cfg.ops)
                 if rest:
                     h.run_batched([rest])
@@ -316,9 +322,13 @@ def _run_batch(template: Template, cfg: FleetConfig, kinds: np.ndarray,
         hb.advance(chunks=1, ops=n * (end - start), bails=len(ids),
                    rejoins=rejoins,
                    residents=len(ids) - rejoins)
-    counts = np.asarray(backend.counts(), dtype=np.int64).copy()
-    for i, c in resident_counts.items():
-        counts[i] = c
+    counts = backend.counts()       # opens the counts-readback span
+    try:
+        counts = np.asarray(counts, dtype=np.int64).copy()
+        for i, c in resident_counts.items():
+            counts[i] = c
+    finally:
+        pop()
     return counts, bails, residents
 
 
@@ -338,11 +348,20 @@ def run_fleet(cfg: FleetConfig, fleet: Optional[Fleet] = None,
     ``resident-replay``; the pallas backend replaces ``chunk-step`` with
     its ``chunk_phase``, ``kernel-interpret``);
     ``heartbeat`` a :class:`repro.obs.Heartbeat`
-    that emits periodic progress lines.  Neither changes counts."""
+    that emits periodic progress lines.  Neither changes counts.
+
+    Whatever ``profile`` is, the run also records native spans
+    (:mod:`repro.obs.profiler`, ``PH_FLEET_*``), nested in those phases:
+    ``template``, ``replicate``, ``state-upload`` and ``compile`` under
+    ``lowering``; ``plan-pack``, ``plan-upload`` and ``step-dispatch``
+    under ``chunk-step``; ``poll-wait`` and ``poll-readback`` under
+    ``poll``; and, after each batch's last chunk, ``counts-wait`` and
+    ``counts-readback``.  The numpy backend records only ``template``,
+    ``replicate`` and ``counts-readback``."""
     prof = profile if profile is not None else _NULL
     hb = heartbeat if heartbeat is not None else _NULL
     t0 = time.perf_counter()
-    prof.push("lowering")
+    prof.push(PH_FLEET_LOWER)
     backend_name, devices = _resolve_backend(cfg.backend, cfg.devices)
     if fleet is None:
         fleet = build_fleet(cfg)
@@ -369,7 +388,7 @@ def run_fleet(cfg: FleetConfig, fleet: Optional[Fleet] = None,
     for s in range(0, cfg.instances, bsz):
         e = min(s + bsz, cfg.instances)
         t1 = time.perf_counter()
-        prof.push("lowering")
+        prof.push(PH_FLEET_LOWER)
         backend = _make_backend(backend_name, t,
                                 replicate(t.row, t.dims, e - s), devices)
         backend.prepare(chunk_lengths)

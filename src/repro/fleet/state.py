@@ -41,6 +41,7 @@ import numpy as np
 from ..core.harness import ALL_QUEUES, QueueHarness
 from ..core.nvram import LINE_WORDS, NVRAM
 from ..core.opsched import NULL, FastPathExecutor
+from ..obs.profiler import PH_FLEET_REPLICATE, PH_FLEET_TEMPLATE, span
 from .lowering import FleetPrograms, lower_queue
 
 _VB = NVRAM._VOLATILE_BASE
@@ -307,56 +308,46 @@ def _pad_u8(a: np.ndarray, n: int) -> np.ndarray:
     return out
 
 
+# each exported row field's dtype in the fleet arrays
+_ROW_DTYPES = (("cached", np.uint8), ("finval", np.uint8),
+               ("everfl", np.uint8), ("persisted", np.uint8),
+               ("vtouched", np.uint8), ("ring_p", np.int32),
+               ("ring_v", np.int32), ("free_p", np.int32),
+               ("vfree", np.int32), ("limbo_a", np.int32),
+               ("limbo_e", np.int32), ("limbo_k", np.uint8),
+               ("counts", np.int64), ("head", np.int32),
+               ("length", np.int32), ("dummy_p", np.int32),
+               ("dummy_v", np.int32), ("nfree", np.int32),
+               ("cursor", np.int32), ("nvfree", np.int32),
+               ("vcursor", np.int32), ("nlimbo", np.int32),
+               ("epoch", np.int32), ("opsctr", np.int32))
+
+
 def replicate(row: dict, dims: FleetDims, n: int) -> FleetState:
     """Tile one exported instance row across N instances."""
-    def tile(v, dtype):
-        if np.isscalar(v):
-            return np.full(n, v, dtype=dtype)
-        return np.repeat(np.asarray(v, dtype=dtype)[None, :], n, axis=0)
-
-    slots = {attr: np.full(n, val, dtype=np.int32)
+    one = {name: np.asarray(row[name], dtype=dtype)[None]
+           for name, dtype in _ROW_DTYPES}
+    one["active"] = np.ones(1, dtype=bool)
+    one["bail_at"] = np.full(1, -1, dtype=np.int32)
+    slots = {attr: np.full(1, val, dtype=np.int32)
              for attr, val in row["slots"].items()}
-    return FleetState(
-        n=n, dims=dims,
-        cached=tile(row["cached"], np.uint8),
-        finval=tile(row["finval"], np.uint8),
-        everfl=tile(row["everfl"], np.uint8),
-        persisted=tile(row["persisted"], np.uint8),
-        vtouched=tile(row["vtouched"], np.uint8),
-        ring_p=tile(row["ring_p"], np.int32),
-        ring_v=tile(row["ring_v"], np.int32),
-        free_p=tile(row["free_p"], np.int32),
-        vfree=tile(row["vfree"], np.int32),
-        limbo_a=tile(row["limbo_a"], np.int32),
-        limbo_e=tile(row["limbo_e"], np.int32),
-        limbo_k=tile(row["limbo_k"], np.uint8),
-        counts=tile(row["counts"], np.int64),
-        head=tile(row["head"], np.int32),
-        length=tile(row["length"], np.int32),
-        dummy_p=tile(row["dummy_p"], np.int32),
-        dummy_v=tile(row["dummy_v"], np.int32),
-        nfree=tile(row["nfree"], np.int32),
-        cursor=tile(row["cursor"], np.int32),
-        nvfree=tile(row["nvfree"], np.int32),
-        vcursor=tile(row["vcursor"], np.int32),
-        nlimbo=tile(row["nlimbo"], np.int32),
-        epoch=tile(row["epoch"], np.int32),
-        opsctr=tile(row["opsctr"], np.int32),
-        active=np.ones(n, dtype=bool),
-        bail_at=np.full(n, -1, dtype=np.int32),
-        slots=slots,
-    )
+    nbytes = n * sum(a.nbytes for a in (*one.values(), *slots.values()))
+    with span(PH_FLEET_REPLICATE, bytes=nbytes):
+        return FleetState(
+            n=n, dims=dims,
+            slots={attr: np.repeat(a, n) for attr, a in slots.items()},
+            **{name: np.repeat(a, n, axis=0) for name, a in one.items()})
 
 
 def build_template(queue_name: str, model, ops: int,
                    prefill: int = DEFAULT_PREFILL) -> Template:
     """Build + warm one template instance and lower its schedules."""
-    queue_cls = ALL_QUEUES[queue_name]
-    h = make_instance_harness(queue_cls, model, area_nodes_for(ops, prefill),
-                              prefill)
-    programs = lower_queue(h.queue, h.nvram.model)
-    dims = derive_dims(h, programs, ops)
-    row = export_instance(h, dims)
+    with span(PH_FLEET_TEMPLATE):
+        h = make_instance_harness(ALL_QUEUES[queue_name], model,
+                                  area_nodes_for(ops, prefill), prefill)
+        programs = lower_queue(h.queue, h.nvram.model)
+        dims = derive_dims(h, programs, ops)
+        row = export_instance(h, dims)
     assert row is not None, "template instance must export cleanly"
     return Template(queue_name=queue_name, model_name=h.nvram.model.name,
                     prefill=prefill, ops=ops, harness=h, programs=programs,

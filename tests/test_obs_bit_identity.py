@@ -9,12 +9,17 @@ identical* to the untelemetered run -- the same contract the PR-3 trace
 tap and the columnar engine are held to (`tests/test_fastpath_equivalence.py`).
 """
 import io
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import pytest
 
 from repro.core import ALL_QUEUES, MEMORY_MODELS, QueueHarness
 from repro.fleet import FleetConfig, run_fleet
-from repro.obs import Heartbeat, PhaseProfiler
+from repro.obs import Heartbeat, PhaseProfiler, profiler
 from benchmarks.workloads import make_plans
 
 QUEUES8 = sorted(ALL_QUEUES)
@@ -110,3 +115,66 @@ def test_fleet_quiet_without_heartbeat():
     test-suite default)."""
     res = run_fleet(_fleet_cfg())
     assert res.counts.shape[0] == 400
+
+
+def _traced_fleet(devices, trace_dir):
+    """A jax-opcode fleet run untraced, then under ``jax.profiler`` with a
+    fresh native span record -> (counts identical, the record's span
+    counts, the trace's host span counts, the expected span counts)."""
+    import jax
+    cfg = FleetConfig(queue="OptLinkedQ", instances=48, ops=16, chunk=8,
+                      backend="jax-opcode", devices=devices, seed=5)
+    ref = run_fleet(cfg)
+    rec, kept = profiler.SpanRecord(), profiler.RECORD
+    profiler.RECORD = rec
+    try:
+        jax.profiler.start_trace(trace_dir)
+        obs = run_fleet(cfg, profile=PhaseProfiler())
+        jax.profiler.stop_trace()
+    finally:
+        profiler.RECORD = kept
+    pd = jax.profiler.ProfileData.from_file(
+        str(next(Path(trace_dir).rglob("*.xplane.pb"))))
+    traced = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in profiler.FLEET_SPANS:
+                        traced[e.name] = traced.get(e.name, 0) + 1
+    chunks = -(-cfg.ops // cfg.chunk)
+    per = {"template": 1, "replicate": 1, "state-upload": 1, "compile": 1,
+           "counts-wait": 1, "counts-readback": 1}
+    per.update({name: chunks for name in (
+        "plan-pack", "plan-upload", "step-dispatch", "poll-wait",
+        "poll-readback")})
+    same = bool((ref.counts == obs.counts).all()) and ref.bails == obs.bails
+    return same, rec.count, traced, per
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_traced_jax_fleet_spans_and_bit_identity(devices, tmp_path):
+    """Under the profiler, every native span of the fleet opens once per
+    chunk or once per pass, in the record and in the trace, and the counts
+    equal an untraced run's bit for bit.  Four devices are virtual CPU
+    devices, in a process of their own."""
+    pytest.importorskip("jax")
+    if devices == 1:
+        same, recorded, traced, per = _traced_fleet(devices, str(tmp_path))
+    else:
+        root = Path(__file__).resolve().parents[1]
+        code = ("import json, test_obs_bit_identity as t\n"
+                f"print(json.dumps(t._traced_fleet({devices}, "
+                f"{str(tmp_path)!r})))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                             f"{devices}",
+                   PYTHONPATH=os.pathsep.join(
+                       [str(root / "src"), str(root), str(root / "tests")]))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert out.returncode == 0, out.stderr[-3000:]
+        same, recorded, traced, per = json.loads(out.stdout.splitlines()[-1])
+    assert same
+    assert recorded == per
+    assert traced == per

@@ -115,3 +115,29 @@ def test_pallas_kernel_compiles_for_v5e(topo, queue):
     fn = make_pallas_chunk_fn(jax, t.programs, t.dims, block=128,
                               interpret=False)
     fn.lower(*args).compile()
+
+
+def test_named_scopes_leave_the_v5e_step_as_it_was(topo, monkeypatch):
+    """The chunk step's named scopes (``epoch-advance``, ``op-enq``,
+    ``op-deq``, ...) are metadata only: with them the v5e step is the
+    module ``jit_chunk`` naming them, and its code is the size it is with
+    every scope left out."""
+    import contextlib
+    one = SingleDeviceSharding(topo.devices[0])
+    t, args = _lowered_args("OptLinkedQ", INSTANCES, one, one)
+
+    def compiled():
+        fn = jax.jit(jaxexec.make_opcode_chunk_fn(jax, t.programs, t.dims),
+                     donate_argnums=(0,))
+        return fn.lower(*args).compile()
+    scoped = compiled()
+    text = scoped.as_text()
+    assert text.startswith("HloModule jit_chunk,")
+    for scope in ("epoch-advance", "op-enq", "op-deq"):
+        assert f"/{scope}/" in text, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "/epoch-advance/" not in bare.as_text()
+    assert (scoped.memory_analysis().generated_code_size_in_bytes
+            == bare.memory_analysis().generated_code_size_in_bytes)
