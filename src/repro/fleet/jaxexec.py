@@ -5,16 +5,19 @@ The op functions are a straight functional transcription of
 :mod:`repro.fleet.stepper` for a *single* instance (scalars + small 1D
 arrays); :func:`_batch_op` batches one op over the instance axis and
 ``lax.scan`` drives the batch down a chunk of the op stream.  Both
-lowered programs run every op as masked straight-line code (no
-per-instance ``lax.cond``: executing the non-selected program under a
+lowered programs run on every instance at every op, under the op's mask
+(no per-instance ``lax.cond``: running the non-selected program under a
 False mask is cheaper than divergence).
 
 Reads and writes at a per-instance index are one-hot selects, and the
 epoch advance compacts with shift networks: under ``vmap`` a gather,
 scatter or sort with one index per instance lowers to TPU code that
 grows with the instance count, and compiles for minutes at 100k
-instances.  The advance runs under a batch-level ``lax.cond``, only in
-steps where some instance advances.
+instances.  The opcode interpreter masks each write at its index: a
+masked-out instance writes at ``_DROP``, an index the one-hot write
+drops, so no whole state array is selected again after an op's row
+loop.  The advance runs under a batch-level ``lax.cond``, only in steps
+where some instance advances.
 
 The compiled step is the XLA module ``jit_chunk``; its ops carry named
 scopes in their HLO metadata (``op-enq``, ``op-deq``, ``epoch-advance``,
@@ -67,7 +70,7 @@ from ..obs.profiler import (
     PH_FLEET_PLAN_PACK, PH_FLEET_PLAN_UPLOAD, PH_FLEET_POLL_READBACK,
     PH_FLEET_POLL_WAIT, PH_FLEET_SLOTS_STACK, PH_FLEET_SLOTS_UNSTACK,
     PH_FLEET_STATE_UPLOAD, PH_FLEET_STEP_DISPATCH, push, span)
-from .lowering import (KIND_DEQ, KIND_ENQ, SYM, N_OPC, OPC_CLASS_P,
+from .lowering import (KIND_DEQ, KIND_ENQ, SYM, OPC_CLASS_P,
                        OPC_CLASS_V, OPC_LIMBO, OPC_PADD, OPC_PDISCARD,
                        OPC_RECACHE, OPC_SLOT, OPC_ST_EVERFL, OPC_ST_INVAL,
                        encode_program)
@@ -89,6 +92,20 @@ _ARRAY_FIELDS = ("cached", "finval", "everfl", "persisted", "vtouched",
 _SCALAR_FIELDS = ("head", "length", "dummy_p", "dummy_v", "nfree", "cursor",
                   "nvfree", "vcursor", "nlimbo", "epoch", "opsctr",
                   "active", "bail_at")
+
+# the values of the opcode interpreter's row loop that each row opcode
+# writes; "cdelta" is the op's count vector
+_OPC_WRITES = {
+    OPC_CLASS_P: ("cached", "finval", "cdelta"),
+    OPC_CLASS_V: ("vtouched", "cdelta"),
+    OPC_ST_INVAL: ("cached", "finval", "everfl"),
+    OPC_ST_EVERFL: ("everfl",),
+    OPC_RECACHE: ("cached", "finval"),
+    OPC_LIMBO: ("limbo_a", "limbo_e", "limbo_k", "nlimbo"),
+    OPC_SLOT: ("slots",),
+    OPC_PDISCARD: ("persisted",),
+    OPC_PADD: ("persisted",),
+}
 
 # each op program's named scope in the compiled step, by FleetProgram.code
 _OP_SCOPE = {KIND_ENQ: PH_FLEET_OP_ENQ, KIND_DEQ: PH_FLEET_OP_DEQ}
@@ -153,6 +170,11 @@ def _put(jnp, x, i, v):
     n = x.shape[0]
     i = jnp.where(i < 0, i + n, i)
     return jnp.where(jnp.arange(n) == i, jnp.asarray(v).astype(x.dtype), x)
+
+
+# an index every _put drops (it counts from the end only when negative):
+# where a masked-out instance writes
+_DROP = np.iinfo(np.int32).max
 
 
 def _bump(jnp, x, i):
@@ -352,6 +374,26 @@ def _batch_op(jax, dims, prog, st, sel, oi, apply):
     return jax.vmap(finish)(st, m)
 
 
+def _fifo_update(jnp, dims, prog, c, m, env):
+    """The logical FIFO update of one instance's state dict ``c``, in
+    place, masked by ``m``: an enqueue writes its ring slot at a masked
+    index, so a masked-out instance writes nothing."""
+    cap = dims.cap
+    length, head = c["length"], c["head"]
+    if prog.code == KIND_ENQ:
+        pos = jnp.where(m, (head + length) % cap, _DROP)
+        new_p = env[E_NEW_P] if prog.allocs_p else 0
+        new_v = env[E_NEW_V] if prog.allocs_v else 0
+        c["ring_p"] = _put(jnp, c["ring_p"], pos, new_p)
+        c["ring_v"] = _put(jnp, c["ring_v"], pos, new_v)
+        c["length"] = jnp.where(m, length + 1, length)
+    else:
+        c["dummy_p"] = jnp.where(m, env[E_NEXT_P], c["dummy_p"])
+        c["dummy_v"] = jnp.where(m, env[E_NEXT_V], c["dummy_v"])
+        c["head"] = jnp.where(m, (head + 1) % cap, head)
+        c["length"] = jnp.where(m, length - 1, length)
+
+
 def _apply_one(jnp, dims, prog, c, m, env):
     """The body of one lowered op on one instance's state dict, masked by
     ``m``, after :func:`_op_env` (unrolled form: every micro/aux entry
@@ -396,22 +438,7 @@ def _apply_one(jnp, dims, prog, c, m, env):
             finval = _put(jnp, finval, ln, zero)
     c["counts"] = jnp.where(m, c["counts"] + cdelta, c["counts"])
     # ---- logical FIFO ----------------------------------------------------
-    cap = dims.cap
-    length, head = c["length"], c["head"]
-    if prog.code == KIND_ENQ:
-        pos = (head + length) % cap
-        new_p = env[E_NEW_P] if prog.allocs_p else jnp.int32(0)
-        new_v = env[E_NEW_V] if prog.allocs_v else jnp.int32(0)
-        c["ring_p"] = jnp.where(m, _put(jnp, c["ring_p"], pos, new_p),
-                                c["ring_p"])
-        c["ring_v"] = jnp.where(m, _put(jnp, c["ring_v"], pos, new_v),
-                                c["ring_v"])
-        c["length"] = jnp.where(m, length + 1, length)
-    else:
-        c["dummy_p"] = jnp.where(m, env[E_NEXT_P], c["dummy_p"])
-        c["dummy_v"] = jnp.where(m, env[E_NEXT_V], c["dummy_v"])
-        c["head"] = jnp.where(m, (head + 1) % cap, head)
-        c["length"] = jnp.where(m, length - 1, length)
+    _fifo_update(jnp, dims, prog, c, m, env)
     # ---- aux effects on local copies ------------------------------------
     limbo_a, limbo_e, limbo_k = c["limbo_a"], c["limbo_e"], c["limbo_k"]
     nlimbo = c["nlimbo"]
@@ -503,8 +530,15 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env,
     ``switch`` interprets the int32 opcode table instead of tracing each
     micro/aux entry, so the jaxpr does not grow with schedule depth.
     Requires the stacked ``slots`` state layout (see
-    :func:`make_opcode_chunk_fn`).  Bit-identical to :func:`_apply_one`:
-    same effect order, same masked commits.
+    :func:`make_opcode_chunk_fn`).  Bit-identical to :func:`_apply_one`,
+    in the same effect order.
+
+    Each row writes under the mask: a masked-out instance writes at
+    ``_DROP``, which :func:`_put` drops, so no state array is selected
+    again after the loop.  The loop carries only what some opcode of the
+    table writes (:data:`_OPC_WRITES`), and its ``switch`` holds a branch
+    for those opcodes only; the table is a host constant, so this is
+    settled at trace time from the program's content.
 
     ``table`` / ``base_counts`` default to trace constants from
     ``opc`` / ``prog``; the Pallas kernel passes them explicitly (a
@@ -517,117 +551,89 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env,
         table = jnp.asarray(opc.table)          # (R, 5) int32
     if base_counts is None:
         base_counts = jnp.asarray(prog.base_counts.astype(np.int32))
+    present = sorted(set(opc.table[:, 0].tolist()) & _OPC_WRITES.keys())
+    keys = sorted({k for op in present for k in _OPC_WRITES[op]})
     epoch = c["epoch"]
     one, zero = jnp.uint8(1), jnp.uint8(0)
 
     def row_step(r, t):
-        (cached, finval, everfl, vtouched, persisted,
-         limbo_a, limbo_e, limbo_k, nlimbo, slots, cdelta) = t
         row = table[r]
         kind, amode, aval, off, imm = (row[0], row[1], row[2], row[3],
                                        row[4])
         bound = envv[jnp.clip(aval, 0, N_SYM - 1)] + off
         a = jnp.where(amode == 1, bound, aval)
         ln = a // LINE_WORDS
+        at_a, at_ln = jnp.where(m, a, _DROP), jnp.where(m, ln, _DROP)
 
         def b_nop(t):
             return t
 
         def b_class_p(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            ev = jnp.where(_take(jnp, ca, ln) == 1, EV_HIT,
-                           jnp.where(_take(jnp, fi, ln) == 1, EV_POSTFLUSH,
-                                     jnp.where(_take(jnp, ev_, ln) == 1,
+            everfl = t.get("everfl", c["everfl"])
+            ev = jnp.where(_take(jnp, t["cached"], ln) == 1, EV_HIT,
+                           jnp.where(_take(jnp, t["finval"], ln) == 1,
+                                     EV_POSTFLUSH,
+                                     jnp.where(_take(jnp, everfl, ln) == 1,
                                                EV_COLD_NVM, EV_COLD_DRAM)))
-            return (_put(jnp, ca, ln, one), _put(jnp, fi, ln, zero), ev_,
-                    vt, pe, la, le, lk, nl, sl, _bump(jnp, cd, ev))
+            return dict(t, cached=_put(jnp, t["cached"], at_ln, one),
+                        finval=_put(jnp, t["finval"], at_ln, zero),
+                        cdelta=_bump(jnp, t["cdelta"], ev))
 
         def b_class_v(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            ev = jnp.where(_take(jnp, vt, a) == 1, EV_HIT, EV_DRAM)
-            return (ca, fi, ev_, _put(jnp, vt, a, one), pe, la, le, lk, nl,
-                    sl, _bump(jnp, cd, ev))
+            ev = jnp.where(_take(jnp, t["vtouched"], a) == 1, EV_HIT, EV_DRAM)
+            return dict(t, vtouched=_put(jnp, t["vtouched"], at_a, one),
+                        cdelta=_bump(jnp, t["cdelta"], ev))
 
         def b_st_inval(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (_put(jnp, ca, ln, zero), _put(jnp, fi, ln, one),
-                    _put(jnp, ev_, ln, one), vt, pe, la, le, lk, nl, sl, cd)
+            return dict(t, cached=_put(jnp, t["cached"], at_ln, zero),
+                        finval=_put(jnp, t["finval"], at_ln, one),
+                        everfl=_put(jnp, t["everfl"], at_ln, one))
 
         def b_st_everfl(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, _put(jnp, ev_, ln, one), vt, pe, la, le, lk,
-                    nl, sl, cd)
+            return dict(t, everfl=_put(jnp, t["everfl"], at_ln, one))
 
         def b_recache(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (_put(jnp, ca, ln, one), _put(jnp, fi, ln, zero), ev_,
-                    vt, pe, la, le, lk, nl, sl, cd)
+            return dict(t, cached=_put(jnp, t["cached"], at_ln, one),
+                        finval=_put(jnp, t["finval"], at_ln, zero))
 
         def b_limbo(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe, _put(jnp, la, nl, a),
-                    _put(jnp, le, nl, epoch), _put(jnp, lk, nl, imm),
-                    nl + 1, sl, cd)
+            nl = t["nlimbo"]
+            at = jnp.where(m, nl, _DROP)
+            return dict(t, limbo_a=_put(jnp, t["limbo_a"], at, a),
+                        limbo_e=_put(jnp, t["limbo_e"], at, epoch),
+                        limbo_k=_put(jnp, t["limbo_k"], at, imm),
+                        nlimbo=nl + m.astype(nl.dtype))
 
         def b_slot(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, pe, la, le, lk, nl,
-                    _put(jnp, sl, imm, a), cd)
+            return dict(t, slots=_put(jnp, t["slots"],
+                                      jnp.where(m, imm, _DROP), a))
 
         def b_pdiscard(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, _put(jnp, pe, ln, zero), la, le, lk,
-                    nl, sl, cd)
+            return dict(t, persisted=_put(jnp, t["persisted"], at_ln, zero))
 
         def b_padd(t):
-            (ca, fi, ev_, vt, pe, la, le, lk, nl, sl, cd) = t
-            return (ca, fi, ev_, vt, _put(jnp, pe, ln, one), la, le, lk,
-                    nl, sl, cd)
+            return dict(t, persisted=_put(jnp, t["persisted"], at_ln, one))
 
-        branches = [b_nop] * N_OPC
-        branches[OPC_CLASS_P] = b_class_p
-        branches[OPC_CLASS_V] = b_class_v
-        branches[OPC_ST_INVAL] = b_st_inval
-        branches[OPC_ST_EVERFL] = b_st_everfl
-        branches[OPC_RECACHE] = b_recache
-        branches[OPC_LIMBO] = b_limbo
-        branches[OPC_SLOT] = b_slot
-        branches[OPC_PDISCARD] = b_pdiscard
-        branches[OPC_PADD] = b_padd
-        return lax.switch(kind, branches, t)
+        branch = {OPC_CLASS_P: b_class_p, OPC_CLASS_V: b_class_v,
+                  OPC_ST_INVAL: b_st_inval, OPC_ST_EVERFL: b_st_everfl,
+                  OPC_RECACHE: b_recache, OPC_LIMBO: b_limbo,
+                  OPC_SLOT: b_slot, OPC_PDISCARD: b_pdiscard,
+                  OPC_PADD: b_padd}
+        # branch 0 is the NOP; opcode present[i] is branch i + 1
+        idx = jnp.int32(0)
+        for i, op in enumerate(present, 1):
+            idx = jnp.where(kind == op, i, idx)
+        return lax.switch(idx, [b_nop] + [branch[op] for op in present], t)
 
-    t = (c["cached"], c["finval"], c["everfl"], c["vtouched"],
-         c["persisted"], c["limbo_a"], c["limbo_e"], c["limbo_k"],
-         c["nlimbo"], c["slots"], base_counts)
+    t = {k: base_counts if k == "cdelta" else c[k] for k in keys}
     # micro rows, then the logical FIFO update, then aux rows -- the same
     # effect order as _apply_one / the numpy stepper
     t = lax.fori_loop(0, opc.n_micro, row_step, t)
-    cap = dims.cap
-    length, head = c["length"], c["head"]
-    if prog.code == KIND_ENQ:
-        pos = (head + length) % cap
-        new_p = env[E_NEW_P] if prog.allocs_p else jnp.int32(0)
-        new_v = env[E_NEW_V] if prog.allocs_v else jnp.int32(0)
-        c["ring_p"] = jnp.where(m, _put(jnp, c["ring_p"], pos, new_p),
-                                c["ring_p"])
-        c["ring_v"] = jnp.where(m, _put(jnp, c["ring_v"], pos, new_v),
-                                c["ring_v"])
-        c["length"] = jnp.where(m, length + 1, length)
-    else:
-        c["dummy_p"] = jnp.where(m, env[E_NEXT_P], c["dummy_p"])
-        c["dummy_v"] = jnp.where(m, env[E_NEXT_V], c["dummy_v"])
-        c["head"] = jnp.where(m, (head + 1) % cap, head)
-        c["length"] = jnp.where(m, length - 1, length)
+    _fifo_update(jnp, dims, prog, c, m, env)
     t = lax.fori_loop(opc.n_micro, opc.n_rows, row_step, t)
-    (cached, finval, everfl, vtouched, persisted,
-     limbo_a, limbo_e, limbo_k, nlimbo, slots, cdelta) = t
+    cdelta = t.pop("cdelta", base_counts)
+    c.update(t)
     c["counts"] = jnp.where(m, c["counts"] + cdelta, c["counts"])
-    for key, val in (("cached", cached), ("finval", finval),
-                     ("everfl", everfl), ("vtouched", vtouched),
-                     ("persisted", persisted), ("limbo_a", limbo_a),
-                     ("limbo_e", limbo_e), ("limbo_k", limbo_k),
-                     ("nlimbo", nlimbo), ("slots", slots)):
-        c[key] = jnp.where(m, val, c[key])
     return c
 
 

@@ -262,7 +262,6 @@ OPC_LIMBO = 6          # aux retire: limbo append (imm 0 = p, 1 = v)
 OPC_SLOT = 7           # aux slot store: slots[imm] = addr (imm: slot index)
 OPC_PDISCARD = 8       # aux persisted.discard(addr line)
 OPC_PADD = 9           # aux persisted.add(addr line) -- one row per sym
-N_OPC = 10
 
 # columns: (kind, amode, a, off, imm).  amode 0 = const (a is an absolute
 # persistent address / volatile offset), amode 1 = sym (a indexes the op

@@ -36,6 +36,23 @@ def test_take_put_bump_follow_jax_indexing(dtype):
                                           x.at[ii].add(1))
 
 
+@pytest.mark.parametrize("dtype", [np.uint8, np.int32])
+@pytest.mark.parametrize("n", [1, 7, 200])
+def test_put_at_the_drop_index_is_the_identity(n, dtype):
+    """A masked-out instance writes at ``_DROP``: nothing changes, for any
+    value and inside ``vmap`` alike."""
+    x = jnp.asarray(np.arange(3, 3 + n) % 251, dtype)
+    for v in (0, 1, 250, -1):
+        np.testing.assert_array_equal(
+            jaxexec._put(jnp, x, jnp.int32(jaxexec._DROP), v), x)
+    xs = jnp.stack([x, x + 1])
+    at = jnp.where(jnp.asarray([True, False]), 0, jaxexec._DROP)
+    got = jax.vmap(partial(jaxexec._put, jnp), in_axes=(0, 0, None))(
+        xs, at, 9)
+    np.testing.assert_array_equal(got[0], x.at[0].set(9))
+    np.testing.assert_array_equal(got[1], xs[1])
+
+
 @pytest.mark.parametrize("n", [1, 2, 7, 64, 200])
 def test_pack_is_a_stable_compaction(n):
     rng = np.random.default_rng(n)

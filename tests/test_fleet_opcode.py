@@ -154,3 +154,74 @@ def test_opcode_trace_size_independent_of_schedule_depth():
         f"opcode trace scaled with depth: {opcode_shallow} -> {opcode_deep}")
     assert unrolled_deep > unrolled_shallow
     assert opcode_deep < unrolled_deep
+
+
+# ---- masked row writes ----------------------------------------------------
+
+BITS = ("cached", "finval", "everfl", "vtouched", "persisted")
+
+
+def _busy_state(t, n, seed):
+    """``n`` instances of the template's chunk-step state with random line
+    and word bits and limbo contents, so that rows read and write values
+    that differ between instances."""
+    from repro.fleet.jaxexec import state_arrays
+    st = state_arrays(replicate(t.row, t.dims, n))
+    rng = np.random.default_rng(seed)
+    for k in BITS:
+        st[k] = rng.integers(0, 2, st[k].shape).astype(st[k].dtype)
+    st["nlimbo"] = rng.integers(0, t.dims.lcap - 8, n).astype(np.int32)
+    for k in ("limbo_a", "limbo_e"):
+        st[k] = rng.integers(0, 5000, st[k].shape).astype(np.int32)
+    return st
+
+
+@pytest.mark.parametrize("queue", list(ALL_QUEUES))
+def test_opcode_body_writes_only_under_the_mask(queue):
+    """For both op programs of each queue, ``_apply_opcode_one`` leaves a
+    masked-out instance's state bit-identical, every array and ``nlimbo``
+    included, and gives a selected instance what the parent's formula
+    gives: the unrolled body's unmasked result, committed under the mask
+    (``where(m, new, old)``)."""
+    from jax import lax
+    jnp = jax.numpy
+    from repro.fleet.jaxexec import _apply_one, _apply_opcode_one, _op_env
+
+    t = build_template(queue, "optane-clwb", ops=32)
+    dims, n = t.dims, 16
+    st = {k: jnp.asarray(v) for k, v in _busy_state(t, n, 15).items()}
+    m = jnp.asarray(np.arange(n) % 3 != 0)
+    slot_keys = ["slot_" + a for a in dims.slot_attrs]
+    for prog in t.programs:
+        opc = encode_program(prog, dims.slot_attrs)
+
+        def opcode(c, m):
+            c = dict(c)
+            slots = [c.pop(k) for k in slot_keys]
+            c["slots"] = jnp.stack(slots) if slots else jnp.zeros(
+                (1,), jnp.int32)
+            c, env = _op_env(jnp, dims, prog, c, m)
+            c = _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env)
+            slots = c.pop("slots")
+            c.update({k: slots[i] for i, k in enumerate(slot_keys)})
+            return c
+
+        def unrolled(c):
+            c, env = _op_env(jnp, dims, prog, c, jnp.asarray(True))
+            return _apply_one(jnp, dims, prog, c, jnp.asarray(True), env)
+
+        got = jax.jit(jax.vmap(opcode))(st, m)
+        new = jax.jit(jax.vmap(unrolled))(st)
+        assert sorted(got) == sorted(st)
+        mask = np.asarray(m)
+        for k in st:
+            old = np.asarray(st[k])
+            g = np.asarray(got[k])
+            np.testing.assert_array_equal(g[~mask], old[~mask],
+                                          err_msg=f"{prog.code} {k}")
+            sel = mask.reshape((n,) + (1,) * (old.ndim - 1))
+            np.testing.assert_array_equal(
+                g, np.where(sel, np.asarray(new[k]), old),
+                err_msg=f"{prog.code} {k}")
+        assert (np.asarray(got["counts"])[mask]
+                != np.asarray(st["counts"])[mask]).any()

@@ -21,6 +21,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
                           SingleDeviceSharding)
 
 from repro.fleet import jaxexec  # noqa: E402
+from repro.fleet.lowering import OPC_LIMBO, encode_program  # noqa: E402
 from repro.fleet.state import build_template, replicate  # noqa: E402
 
 QUEUES = ["DurableMSQ", "OptLinkedQ"]
@@ -32,6 +33,11 @@ COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|collective-permute|"
 # per-instance indexing under vmap: the TPU compiler emits code per
 # instance for these (889 MB and minutes of compile at 100k instances)
 INDEXED = re.compile(r"\b(gather|scatter|sort)\(")
+# an op program's whole-array select outside its row loop
+OP_SELECT = re.compile(r'op_name="[^"]*/op-(enq|deq)/vmap\(jit\(_where\)\)'
+                       r'/select_n"')
+# an enqueue program's row loop
+ENQ_ROW_LOOP = re.compile(r'op_name="[^"]*/op-enq/vmap\(\)/while"')
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +92,30 @@ def test_chunk_step_compiles_for_one_v5e_chip(topo, queue, make):
     state_bytes = sum(s.size * s.dtype.itemsize for s in args[0].values())
     assert mem.alias_size_in_bytes >= state_bytes * 0.99
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("queue", QUEUES)
+def test_op_programs_leave_limbo_to_their_row_loops(topo, queue):
+    """Each opcode row writes under the op's mask, so no op program
+    selects a whole ``[N, lcap]`` limbo array after its row loop; and the
+    enqueue program, whose table has no ``OPC_LIMBO`` row, carries no
+    limbo array through its row loop."""
+    one = SingleDeviceSharding(topo.devices[0])
+    t, args = _lowered_args(queue, INSTANCES, one, one)
+    enq = encode_program(t.programs.enq, t.dims.slot_attrs)
+    assert OPC_LIMBO not in enq.table[:, 0]
+    fn = jax.jit(jaxexec.make_opcode_chunk_fn(jax, t.programs, t.dims),
+                 donate_argnums=(0,))
+    lines = fn.lower(*args).compile().as_text().splitlines()
+    limbo = f"[{INSTANCES},{t.dims.lcap}]"
+    selects = [ln.split(" fusion(")[0] for ln in lines
+               if " fusion(" in ln and OP_SELECT.search(ln)]
+    assert selects                  # the per-instance scalars' commits
+    assert not [ln for ln in selects if limbo in ln]
+    loops = [ln.split(" while(")[0] for ln in lines
+             if " while(" in ln and ENQ_ROW_LOOP.search(ln)]
+    assert loops
+    assert not [ln for ln in loops if limbo in ln]
 
 
 def test_opcode_step_shards_over_four_chips_without_collectives(topo):
