@@ -21,9 +21,11 @@ where some instance advances.
 
 The compiled step is the XLA module ``jit_chunk``; its ops carry named
 scopes in their HLO metadata (``op-enq``, ``op-deq``, ``epoch-advance``,
-``slots-stack``, ``slots-unstack``: ``repro.obs.profiler.PH_FLEET_*``),
-and the backend records native spans around its host work (plan packing
-and upload, dispatch, polls, the counts' readback, state upload, compile).
+``slots-stack``, ``slots-unstack``; inside an op program its ``guards``
+and one ``row-<opcode>`` scope per opcode row kind of the interpreter:
+``repro.obs.profiler.PH_FLEET_*``), and the backend records native
+spans around its host work (plan packing and upload, dispatch, polls,
+the counts' readback, state upload, compile).
 
 The instance axis is sharded over a 1D mesh of the first ``devices`` JAX
 devices (TPU chips; on the CPU platform, host devices, of which there
@@ -66,10 +68,14 @@ from ..core.nvram import (EV_COLD_DRAM, EV_COLD_NVM, EV_DRAM, EV_HIT,
 from ..core.opsched import NULL, ST_EVERFL, ST_INVAL
 from ..obs.profiler import (
     PH_FLEET_ADVANCE, PH_FLEET_COMPILE, PH_FLEET_COUNTS_READBACK,
-    PH_FLEET_COUNTS_WAIT, PH_FLEET_KERNEL, PH_FLEET_OP_DEQ, PH_FLEET_OP_ENQ,
-    PH_FLEET_PLAN_PACK, PH_FLEET_PLAN_UPLOAD, PH_FLEET_POLL_READBACK,
-    PH_FLEET_POLL_WAIT, PH_FLEET_SLOTS_STACK, PH_FLEET_SLOTS_UNSTACK,
-    PH_FLEET_STATE_UPLOAD, PH_FLEET_STEP_DISPATCH, push, span)
+    PH_FLEET_COUNTS_WAIT, PH_FLEET_GUARDS, PH_FLEET_KERNEL, PH_FLEET_OP_DEQ,
+    PH_FLEET_OP_ENQ, PH_FLEET_PLAN_PACK, PH_FLEET_PLAN_UPLOAD,
+    PH_FLEET_POLL_READBACK, PH_FLEET_POLL_WAIT, PH_FLEET_ROW_CLASS_P,
+    PH_FLEET_ROW_CLASS_V, PH_FLEET_ROW_LIMBO, PH_FLEET_ROW_PADD,
+    PH_FLEET_ROW_PDISCARD, PH_FLEET_ROW_RECACHE, PH_FLEET_ROW_SLOT,
+    PH_FLEET_ROW_ST_EVERFL, PH_FLEET_ROW_ST_INVAL, PH_FLEET_SLOTS_STACK,
+    PH_FLEET_SLOTS_UNSTACK, PH_FLEET_STATE_UPLOAD, PH_FLEET_STEP_DISPATCH,
+    push, span)
 from .lowering import (KIND_DEQ, KIND_ENQ, SYM, OPC_CLASS_P,
                        OPC_CLASS_V, OPC_LIMBO, OPC_PADD, OPC_PDISCARD,
                        OPC_RECACHE, OPC_SLOT, OPC_ST_EVERFL, OPC_ST_INVAL,
@@ -109,6 +115,19 @@ _OPC_WRITES = {
 
 # each op program's named scope in the compiled step, by FleetProgram.code
 _OP_SCOPE = {KIND_ENQ: PH_FLEET_OP_ENQ, KIND_DEQ: PH_FLEET_OP_DEQ}
+
+# each opcode row kind's named scope: its ``switch`` branch in the row loop
+_ROW_SCOPE = {
+    OPC_CLASS_P: PH_FLEET_ROW_CLASS_P,
+    OPC_CLASS_V: PH_FLEET_ROW_CLASS_V,
+    OPC_ST_INVAL: PH_FLEET_ROW_ST_INVAL,
+    OPC_ST_EVERFL: PH_FLEET_ROW_ST_EVERFL,
+    OPC_RECACHE: PH_FLEET_ROW_RECACHE,
+    OPC_LIMBO: PH_FLEET_ROW_LIMBO,
+    OPC_SLOT: PH_FLEET_ROW_SLOT,
+    OPC_PDISCARD: PH_FLEET_ROW_PDISCARD,
+    OPC_PADD: PH_FLEET_ROW_PADD,
+}
 
 # the newest compiled chunk steps of this process, for readers of a device
 # trace that map an op back to its named scope (:func:`compiled_steps`);
@@ -283,23 +302,27 @@ def _slot(dims, c, attr):
     return c["slot_" + attr]
 
 
-def _op_begin(jnp, dims, prog, c, sel, oi):
+def _op_begin(jax, dims, prog, c, sel, oi):
     """The front of one lowered op on one instance's state dict: bail
-    detection and op_begin's op counter.  Returns ``(c, m, adv)``: the
-    op's mask and whether this op advances the epoch."""
+    detection (the program's guards under the ``guards`` scope) and
+    op_begin's op counter.  Returns ``(c, m, adv)``: the op's mask and
+    whether this op advances the epoch."""
+    jnp = jax.numpy
     c = dict(c)
     m = c["active"] & sel
-    tail_p, _ = _tail(jnp, dims, c)
     # ---- bail detection --------------------------------------------------
     bail = jnp.asarray(False)
     if prog.code == KIND_DEQ:
         bail = bail | (c["length"] == 0)
-    for g in prog.guards:
-        if g[0] == "slot_nonnull":
-            bail = bail | (_slot(dims, c, g[1]) == NULL)
-        else:                               # tail_persisted
-            bail = bail | (_take(jnp, c["persisted"],
-                                 tail_p // LINE_WORDS) == 0)
+    if prog.guards:
+        with jax.named_scope(PH_FLEET_GUARDS):
+            tail_p, _ = _tail(jnp, dims, c)
+            for g in prog.guards:
+                if g[0] == "slot_nonnull":
+                    bail = bail | (_slot(dims, c, g[1]) == NULL)
+                else:                       # tail_persisted
+                    bail = bail | (_take(jnp, c["persisted"],
+                                         tail_p // LINE_WORDS) == 0)
     if prog.allocs_p:
         bail = bail | ((c["nfree"] == 0) & (c["cursor"] >= dims.area_cap))
     if prog.allocs_v:
@@ -360,7 +383,7 @@ def _batch_op(jax, dims, prog, st, sel, oi, apply):
     scatters would otherwise run, masked, on every op.  Skipping it when
     no instance advances is exact, since it is the identity for those."""
     jnp, lax = jax.numpy, jax.lax
-    st, m, adv = jax.vmap(partial(_op_begin, jnp, dims, prog),
+    st, m, adv = jax.vmap(partial(_op_begin, jax, dims, prog),
                           in_axes=(0, 0, None))(st, sel, oi)
     if prog.uses_ssmem:
         with jax.named_scope(PH_FLEET_ADVANCE):
@@ -540,9 +563,13 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env,
     for those opcodes only; the table is a host constant, so this is
     settled at trace time from the program's content.
 
+    Each opcode's branch runs under its ``row-<opcode>`` named scope
+    (:data:`_ROW_SCOPE`).
+
     ``table`` / ``base_counts`` default to trace constants from
     ``opc`` / ``prog``; the Pallas kernel passes them explicitly (a
     kernel cannot capture array constants)."""
+    import jax
     # dense env vector for data-driven sym gathers (static keys)
     envv = jnp.zeros((N_SYM,), jnp.int32)
     for k, v in env.items():
@@ -619,11 +646,18 @@ def _apply_opcode_one(jnp, lax, dims, prog, opc, c, m, env,
                   OPC_RECACHE: b_recache, OPC_LIMBO: b_limbo,
                   OPC_SLOT: b_slot, OPC_PDISCARD: b_pdiscard,
                   OPC_PADD: b_padd}
+
+        def scoped(op):                 # the branch under its row scope
+            def run(t):
+                with jax.named_scope(_ROW_SCOPE[op]):
+                    return branch[op](t)
+            return run
+
         # branch 0 is the NOP; opcode present[i] is branch i + 1
         idx = jnp.int32(0)
         for i, op in enumerate(present, 1):
             idx = jnp.where(kind == op, i, idx)
-        return lax.switch(idx, [b_nop] + [branch[op] for op in present], t)
+        return lax.switch(idx, [b_nop] + [scoped(op) for op in present], t)
 
     t = {k: base_counts if k == "cdelta" else c[k] for k in keys}
     # micro rows, then the logical FIFO update, then aux rows -- the same

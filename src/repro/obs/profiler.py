@@ -80,6 +80,17 @@ PH_FLEET_OP_ENQ = "op-enq"          # one step of the enqueue program
 PH_FLEET_OP_DEQ = "op-deq"          # one step of the dequeue program
 PH_FLEET_SLOTS_STACK = "slots-stack"      # slot_<attr> keys -> slots matrix
 PH_FLEET_SLOTS_UNSTACK = "slots-unstack"  # and back, after the scan
+PH_FLEET_GUARDS = "guards"          # an op program's bail guards
+# one opcode row kind of an op program's row loop (its ``switch`` branch)
+PH_FLEET_ROW_CLASS_P = "row-class-p"      # persistent line classified
+PH_FLEET_ROW_CLASS_V = "row-class-v"      # volatile word classified
+PH_FLEET_ROW_ST_INVAL = "row-st-inval"    # line flushed (invalidated)
+PH_FLEET_ROW_ST_EVERFL = "row-st-everfl"  # line flushed (kept cached)
+PH_FLEET_ROW_RECACHE = "row-recache"      # line cached again
+PH_FLEET_ROW_LIMBO = "row-limbo"          # limbo append
+PH_FLEET_ROW_SLOT = "row-slot"            # guard slot stored
+PH_FLEET_ROW_PDISCARD = "row-pdiscard"    # persisted-node hint cleared
+PH_FLEET_ROW_PADD = "row-padd"            # persisted-node hint set
 
 # Crash-sweep phases (repro.crash.sweep).
 PH_CRASH_CAPTURE = "capture"        # boundary capture run

@@ -7,6 +7,7 @@ the module ``jit_chunk``, whose HLO metadata names its scopes; and the
 import rule holds: `repro.core` and `repro.crash` never import `repro.obs`,
 `repro.fleet` imports `repro.obs.profiler` only."""
 import ast
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -160,3 +161,20 @@ def test_chunk_module_is_named_and_carries_its_scopes(queue):
     for scope in (profiler.PH_FLEET_ADVANCE, profiler.PH_FLEET_OP_ENQ,
                   profiler.PH_FLEET_OP_DEQ):
         assert f"/{scope}/" in text, scope
+
+
+HINT_AND_GUARD_SCOPES = (profiler.PH_FLEET_GUARDS, profiler.PH_FLEET_ROW_SLOT,
+                         profiler.PH_FLEET_ROW_PDISCARD,
+                         profiler.PH_FLEET_ROW_PADD)
+
+
+@pytest.mark.parametrize("queue,carried", [("LinkedQ", True),
+                                           ("DurableMSQ", False)])
+def test_guards_and_hint_rows_are_scoped_where_the_program_has_them(
+        queue, carried):
+    """LinkedQ's step names its guards and its slot and persisted-set rows;
+    DurableMSQ's program has none of them, so its step names none (a scope
+    opened first inside a vmap shows as ``vmap(<scope>)``)."""
+    text = _compiled_step(queue).as_text()
+    for scope in HINT_AND_GUARD_SCOPES:
+        assert bool(re.search(rf"[/(]{scope}[/)]", text)) == carried, scope

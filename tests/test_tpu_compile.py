@@ -10,7 +10,9 @@ this module is imported: only one process at a time may load the TPU
 library, and the test workers all import every test module.  Keep every
 such compile in this one file, so that one worker loads the library.
 """
+import json
 import re
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -24,7 +26,10 @@ from repro.fleet import jaxexec  # noqa: E402
 from repro.fleet.lowering import OPC_LIMBO, encode_program  # noqa: E402
 from repro.fleet.state import build_template, replicate  # noqa: E402
 
-QUEUES = ["DurableMSQ", "OptLinkedQ"]
+QUEUES = ["DurableMSQ", "OptLinkedQ", "LinkedQ"]
+# the chip benchmark's LinkedQ configuration: one chip's whole fleet
+LINKEDQ_CONFIG = (Path(__file__).resolve().parents[1] / "benchmarks" /
+                  "chip" / "configs" / "linkedq-optane-400k.json")
 INSTANCES = 1024
 OPS, PREFILL, CHUNK = 96, 10, 48      # the chip smoke's per-instance widths
 HBM_BYTES = 16 * 2**30                # one v5e chip
@@ -94,6 +99,23 @@ def test_chunk_step_compiles_for_one_v5e_chip(topo, queue, make):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+def test_linkedq_step_fits_one_v5e_at_the_benchmarked_size(topo):
+    """LinkedQ's jax-opcode step at the chip benchmark's tenant count
+    fits one chip beside a second copy of its state (a pass's untouched
+    initial state), with no gather, scatter or sort."""
+    n = json.loads(LINKEDQ_CONFIG.read_text())["tenants"]
+    one = SingleDeviceSharding(topo.devices[0])
+    t, args = _lowered_args("LinkedQ", n, one, one)
+    fn = jax.jit(jaxexec.make_opcode_chunk_fn(jax, t.programs, t.dims),
+                 donate_argnums=(0,))
+    compiled = fn.lower(*args).compile()
+    assert not INDEXED.findall(compiled.as_text())
+    mem = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize for s in args[0].values())
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + state_bytes) < HBM_BYTES
+
+
 @pytest.mark.parametrize("queue", QUEUES)
 def test_op_programs_leave_limbo_to_their_row_loops(topo, queue):
     """Each opcode row writes under the op's mask, so no op program
@@ -149,25 +171,39 @@ def test_pallas_kernel_compiles_for_v5e(topo, queue):
 
 def test_named_scopes_leave_the_v5e_step_as_it_was(topo, monkeypatch):
     """The chunk step's named scopes (``epoch-advance``, ``op-enq``,
-    ``op-deq``, ...) are metadata only: with them the v5e step is the
+    ``op-deq``, the ``guards`` and the ``row-<opcode>`` scopes inside an
+    op program, ...) are metadata only: with them the v5e step is the
     module ``jit_chunk`` naming them, and its code is the size it is with
-    every scope left out."""
+    every scope left out, on OptLinkedQ's and LinkedQ's steps (the
+    guards and the hint rows are theirs alone)."""
     import contextlib
     one = SingleDeviceSharding(topo.devices[0])
-    t, args = _lowered_args("OptLinkedQ", INSTANCES, one, one)
+    scopes = {"OptLinkedQ": ("epoch-advance", "op-enq", "op-deq", "guards",
+                             "row-class-v", "row-pdiscard", "row-padd",
+                             "row-limbo"),
+              "LinkedQ": ("epoch-advance", "op-enq", "op-deq", "guards",
+                          "row-class-p", "row-st-inval", "row-recache",
+                          "row-slot", "row-pdiscard", "row-padd",
+                          "row-limbo")}
 
-    def compiled():
+    def compiled(queue):
+        t, args = _lowered_args(queue, INSTANCES, one, one)
         fn = jax.jit(jaxexec.make_opcode_chunk_fn(jax, t.programs, t.dims),
                      donate_argnums=(0,))
         return fn.lower(*args).compile()
-    scoped = compiled()
-    text = scoped.as_text()
-    assert text.startswith("HloModule jit_chunk,")
-    for scope in ("epoch-advance", "op-enq", "op-deq"):
-        assert f"/{scope}/" in text, scope
+    scoped = {queue: compiled(queue) for queue in scopes}
+    for queue, step in scoped.items():
+        text = step.as_text()
+        assert text.startswith("HloModule jit_chunk,")
+        for scope in scopes[queue]:
+            # a scope opened first inside a vmap shows as vmap(<scope>)
+            assert re.search(rf"[/(]{scope}[/)]", text), (queue, scope)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    bare = compiled()
-    assert "/epoch-advance/" not in bare.as_text()
-    assert (scoped.memory_analysis().generated_code_size_in_bytes
-            == bare.memory_analysis().generated_code_size_in_bytes)
+    for queue, step in scoped.items():
+        bare = compiled(queue)
+        assert not re.search(r"[/(](epoch-advance|guards|row-[a-z\-]+)[/)]",
+                             bare.as_text())
+        assert (step.memory_analysis().generated_code_size_in_bytes
+                == bare.memory_analysis().generated_code_size_in_bytes), \
+            queue
