@@ -3,11 +3,11 @@
 
 The op functions are a straight functional transcription of
 :mod:`repro.fleet.stepper` for a *single* instance (scalars + small 1D
-arrays); :func:`_batch_op` batches one op over the instance axis and
-``lax.scan`` drives the batch down a chunk of the op stream.  Both
-lowered programs run on every instance at every op, under the op's mask
-(no per-instance ``lax.cond``: running the non-selected program under a
-False mask is cheaper than divergence).
+arrays); :func:`_batch_step` batches one op step of both lowered programs
+over the instance axis and ``lax.scan`` drives the batch down a chunk of
+the op stream.  Both programs run on every instance at every op, under
+the op's mask (no per-instance ``lax.cond``: running the non-selected
+program under a False mask is cheaper than divergence).
 
 Reads and writes at a per-instance index are one-hot selects, and the
 epoch advance compacts with shift networks: under ``vmap`` a gather,
@@ -16,12 +16,15 @@ grows with the instance count, and compiles for minutes at 100k
 instances.  The opcode interpreter masks each write at its index: a
 masked-out instance writes at ``_DROP``, an index the one-hot write
 drops, so no whole state array is selected again after an op's row
-loop.  The advance runs under a batch-level ``lax.cond``, only in steps
-where some instance advances.
+loop.  The epoch advance runs once per scan step, between the programs'
+bail detection and their bodies, over every instance that advances in
+either program: one batch-level ``lax.cond``, taken only in steps where
+some instance advances.
 
 The compiled step is the XLA module ``jit_chunk``; its ops carry named
-scopes in their HLO metadata (``op-enq``, ``op-deq``, ``epoch-advance``,
-``slots-stack``, ``slots-unstack``; inside an op program its ``guards``
+scopes in their HLO metadata (``op-enq``, ``op-deq``, ``slots-stack``,
+``slots-unstack``; ``epoch-advance`` at step level, beside the op
+scopes, not under them; inside an op program its ``guards``
 and one ``row-<opcode>`` scope per opcode row kind of the interpreter:
 ``repro.obs.profiler.PH_FLEET_*``), and the backend records native
 spans around its host work (plan packing and upload, dispatch, polls,
@@ -41,7 +44,7 @@ needs jax x64 mode.  Bit-identity with the numpy stepper (and hence with
 ``run_batched``) is asserted by ``tests/test_fleet_equivalence.py``.
 
 Two steppers share the sections that don't depend on schedule depth
-(:func:`_batch_op`: tail record, bail detection, epoch machinery, env
+(:func:`_batch_step`: tail record, bail detection, epoch machinery, env
 binding + allocations):
 
 * the original **unrolled** stepper (:func:`_apply_one` /
@@ -371,30 +374,51 @@ def _op_env(jnp, dims, prog, c, m):
     return c, env
 
 
-def _batch_op(jax, dims, prog, st, sel, oi, apply):
-    """One lowered op across a batch of instances (leading axis), masked
-    by ``sel``: vmapped bail detection and op counter, the epoch advance,
-    then the vmapped env binding and ``apply(c, m, env) -> c``, the op's
-    depth-dependent body.  Shared by the unrolled and opcode steppers and
-    the Pallas kernel.
+def _op_finish(jnp, dims, prog, apply, c, m):
+    """The back of one lowered op on one instance's state dict, after the
+    step's epoch advance: env binding and allocations, then ``apply(c, m,
+    env) -> c``, the op's depth-dependent body."""
+    c, env = _op_env(jnp, dims, prog, c, m)
+    return apply(c, m, env)
 
-    The advance is taken only in steps where some instance advances
-    (``lax.cond`` on the whole batch): under ``vmap`` its sort and
-    scatters would otherwise run, masked, on every op.  Skipping it when
-    no instance advances is exact, since it is the identity for those."""
+
+def _batch_step(jax, dims, ops, st, k, oi):
+    """One scan step of every op program across a batch of instances
+    (leading axis).  ``ops`` is ``[(prog, apply)]``; instance ``i`` runs
+    the program whose code is ``k[i]``.  Each program's vmapped bail
+    detection and op counter runs under its own mask, then one epoch
+    advance for the whole step, then each program's vmapped env binding
+    and ``apply``.  Shared by the unrolled and opcode steppers and the
+    Pallas kernel.
+
+    The advance runs once per scan step, over every instance that
+    advances in any program that uses SSMem, and only in steps where some
+    instance advances (``lax.cond`` on the whole batch): under ``vmap``
+    its compaction would otherwise run, masked, on every op.  This is
+    exact.  The advance is the identity for an instance that does not
+    advance, and depends only on the instance's own state.  An instance
+    is selected by at most one program per step, and every other
+    program's begin, env binding and rows are masked no-ops for it; so
+    the numpy stepper's order (begin, advance, finish, one program after
+    the other) leaves every instance in the state this order does."""
     jnp, lax = jax.numpy, jax.lax
-    st, m, adv = jax.vmap(partial(_op_begin, jax, dims, prog),
-                          in_axes=(0, 0, None))(st, sel, oi)
-    if prog.uses_ssmem:
+    masks, adv = [], None
+    for prog, _ in ops:
+        with jax.named_scope(_OP_SCOPE[prog.code]):
+            st, m, a = jax.vmap(partial(_op_begin, jax, dims, prog),
+                                in_axes=(0, 0, None))(st, k == prog.code, oi)
+        masks.append(m)
+        if prog.uses_ssmem:
+            adv = a if adv is None else adv | a
+    if adv is not None:
         with jax.named_scope(PH_FLEET_ADVANCE):
             st = lax.cond(jnp.any(adv),
                           jax.vmap(partial(_advance_one, jnp, dims)),
                           lambda s, a: s, st, adv)
-
-    def finish(c, m):
-        c, env = _op_env(jnp, dims, prog, c, m)
-        return apply(c, m, env)
-    return jax.vmap(finish)(st, m)
+    for (prog, apply), m in zip(ops, masks):
+        with jax.named_scope(_OP_SCOPE[prog.code]):
+            st = jax.vmap(partial(_op_finish, jnp, dims, prog, apply))(st, m)
+    return st
 
 
 def _fifo_update(jnp, dims, prog, c, m, env):
@@ -529,16 +553,14 @@ def _per_device(jax, chunk, mesh):
 
 def make_chunk_fn(jax, programs, dims, mesh=None):
     """-> chunk(st, kcols, oi): a lax.scan over the chunk's op stream,
-    each op vmapped over the instances (:func:`_batch_op`), per device
-    of ``mesh`` (:func:`_per_device`)."""
+    each step every op program vmapped over the instances with one epoch
+    advance (:func:`_batch_step`), per device of ``mesh``
+    (:func:`_per_device`)."""
     import jax.numpy as jnp
 
-    def step_op(st, k, o):
-        for prog in programs:
-            with jax.named_scope(_OP_SCOPE[prog.code]):
-                st = _batch_op(jax, dims, prog, st, k == prog.code, o,
-                               partial(_apply_one, jnp, dims, prog))
-        return st
+    step_op = partial(_batch_step, jax, dims,
+                      [(p, partial(_apply_one, jnp, dims, p))
+                       for p in programs])
 
     def chunk(st, kcols, oi):
         return _scan_chunk(jax, st, kcols, oi, step_op)
@@ -680,15 +702,10 @@ def make_opcode_chunk_fn(jax, programs, dims, mesh=None):
     import jax.numpy as jnp
     from jax import lax
 
-    progs = [(p, encode_program(p, dims.slot_attrs)) for p in programs]
-
-    def step_op(st, k, o):
-        for prog, opc in progs:
-            with jax.named_scope(_OP_SCOPE[prog.code]):
-                st = _batch_op(jax, dims, prog, st, k == prog.code, o,
-                               partial(_apply_opcode_one, jnp, lax, dims,
-                                       prog, opc))
-        return st
+    step_op = partial(_batch_step, jax, dims,
+                      [(p, partial(_apply_opcode_one, jnp, lax, dims, p,
+                                   encode_program(p, dims.slot_attrs)))
+                       for p in programs])
 
     def chunk(st, kcols, oi):
         st = dict(st)

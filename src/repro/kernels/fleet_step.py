@@ -6,7 +6,8 @@ axis into blocks; each program id owns one block of rows from every state
 array, loops over the chunk's ops with ``lax.fori_loop`` and applies the
 *same* opcode interpreter the jax-opcode backend scans with
 (:func:`repro.fleet.jaxexec._apply_opcode_one`, vmapped over the block
-by :func:`repro.fleet.jaxexec._batch_op`).
+with one epoch advance per op step by
+:func:`repro.fleet.jaxexec._batch_step`).
 Sharing the interpreter is the point: the kernel adds a memory layout
 (explicit per-block refs, one launch per chunk instead of one dispatch
 per op), not a second semantics to keep bit-identical.
@@ -28,7 +29,7 @@ from __future__ import annotations
 from functools import partial
 
 from ..fleet.jaxexec import (_ARRAY_FIELDS, _SCALAR_FIELDS,
-                             _apply_opcode_one, _batch_op)
+                             _apply_opcode_one, _batch_step)
 from ..fleet.lowering import encode_program
 
 # state-dict keys in ref order; "slots" is the stacked guard-slot matrix
@@ -56,13 +57,12 @@ def make_pallas_chunk_fn(jax, programs, dims, block: int = 128,
         const_arrays.append(p.base_counts.astype(np.int32))
     n_const = len(const_arrays)
 
-    def apply_block(st, k, o, prog, opc, table, bc):
-        return _batch_op(jax, dims, prog, st, k == prog.code, o,
-                         partial(_apply_opcode_one, jnp, lax, dims, prog,
-                                 opc, table=table, base_counts=bc))
-
     def kernel(kc_ref, oi_ref, *refs):
         consts = [r[...] for r in refs[:n_const]]
+        ops = [(prog, partial(_apply_opcode_one, jnp, lax, dims, prog, opc,
+                              table=consts[2 * j],
+                              base_counts=consts[2 * j + 1]))
+               for j, (prog, opc) in enumerate(progs)]
         state_in = refs[n_const:n_const + len(STATE_KEYS)]
         state_out = refs[n_const + len(STATE_KEYS):]
         st = {key: r[...] for key, r in zip(STATE_KEYS, state_in)}
@@ -72,10 +72,7 @@ def make_pallas_chunk_fn(jax, programs, dims, block: int = 128,
         def step_op(ci, st):
             k = lax.dynamic_index_in_dim(kc, ci, axis=1, keepdims=False)
             o = lax.dynamic_index_in_dim(oi, ci, keepdims=False)
-            for j, (prog, opc) in enumerate(progs):
-                st = apply_block(st, k, o, prog, opc,
-                                 consts[2 * j], consts[2 * j + 1])
-            return st
+            return _batch_step(jax, dims, ops, st, k, o)
 
         st = lax.fori_loop(0, kc.shape[1], step_op, st)
         for key, r in zip(STATE_KEYS, state_out):

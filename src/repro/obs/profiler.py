@@ -75,7 +75,7 @@ FLEET_SPANS = (PH_FLEET_TEMPLATE, PH_FLEET_REPLICATE, PH_FLEET_STATE_UPLOAD,
                PH_FLEET_COUNTS_READBACK)
 
 # Named scopes in the fleet's compiled chunk step (HLO op_name metadata).
-PH_FLEET_ADVANCE = "epoch-advance"  # the batch-level lax.cond of the advance
+PH_FLEET_ADVANCE = "epoch-advance"  # the step's one lax.cond of the advance
 PH_FLEET_OP_ENQ = "op-enq"          # one step of the enqueue program
 PH_FLEET_OP_DEQ = "op-deq"          # one step of the dequeue program
 PH_FLEET_SLOTS_STACK = "slots-stack"      # slot_<attr> keys -> slots matrix

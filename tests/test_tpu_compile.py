@@ -41,6 +41,7 @@ INDEXED = re.compile(r"\b(gather|scatter|sort)\(")
 # an op program's whole-array select outside its row loop
 OP_SELECT = re.compile(r'op_name="[^"]*/op-(enq|deq)/vmap\(jit\(_where\)\)'
                        r'/select_n"')
+OP_NAME = re.compile(r'op_name="([^"]*)"')
 # an enqueue program's row loop
 ENQ_ROW_LOOP = re.compile(r'op_name="[^"]*/op-enq/vmap\(\)/while"')
 
@@ -138,6 +139,26 @@ def test_op_programs_leave_limbo_to_their_row_loops(topo, queue):
              if " while(" in ln and ENQ_ROW_LOOP.search(ln)]
     assert loops
     assert not [ln for ln in loops if limbo in ln]
+
+
+@pytest.mark.parametrize("queue", QUEUES)
+def test_epoch_advance_runs_once_per_scan_step(topo, queue):
+    """Both op programs advance the epoch, and the step holds one
+    conditional for it, at step level under the ``epoch-advance`` scope
+    (the scope ``advance_ns_per_op`` reads), not one under each op
+    program's scope."""
+    one = SingleDeviceSharding(topo.devices[0])
+    t, args = _lowered_args(queue, INSTANCES, one, one)
+    assert all(p.uses_ssmem for p in t.programs)
+    fn = jax.jit(jaxexec.make_opcode_chunk_fn(jax, t.programs, t.dims),
+                 donate_argnums=(0,))
+    text = fn.lower(*args).compile().as_text()
+    names = [OP_NAME.search(ln).group(1) for ln in text.splitlines()
+             if " conditional(" in ln and OP_NAME.search(ln)]
+    advance = [n for n in names if "/epoch-advance/" in n]
+    assert len(advance) == 1, advance
+    assert "/while/body/" in advance[0]
+    assert not re.search(r"/op-(enq|deq)/", advance[0]), advance[0]
 
 
 def test_opcode_step_shards_over_four_chips_without_collectives(topo):
